@@ -1,13 +1,14 @@
 """Roofline view of the numeric solve, from ``BENCH_solve.json`` records.
 
 Per matrix: the two roofline terms of the dense-front work
-    compute = front FLOPs / PEAK_FLOPS
-    memory  = front workspace bytes / HBM_BW
-(recomputed here from the raw fields so the peak constants can evolve
-without re-running the bench), the dominant bottleneck, and per backend the
-achieved GFLOP/s and its fraction of the compute roof. Run
-``benchmarks/solve_bench.py`` first to produce the input; this is a pure
-formatter of its records.
+    compute = front FLOPs / peak FLOP/s
+    memory  = front workspace bytes / peak HBM bytes/s
+with the peaks the bench recorded for its device
+(``benchmarks.solve_bench.PEAKS``, keyed by device kind), the dominant
+bottleneck, and per backend the achieved GFLOP/s and its fraction of the
+compute roof. A bench run on the CPU records no peaks; its roofline
+columns read "not measured". Run ``benchmarks/solve_bench.py`` first to
+produce the input; this is a pure formatter of its records.
 """
 from __future__ import annotations
 
@@ -15,8 +16,7 @@ import json
 import os
 import sys
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+NOT_MEASURED = "not measured"
 
 DEFAULT_PATH = os.environ.get("REPRO_BENCH_SOLVE", "BENCH_solve.json")
 
@@ -26,25 +26,33 @@ def load(path: str = DEFAULT_PATH) -> dict:
         return json.load(fh)
 
 
-def terms_of(rec: dict):
-    compute = rec["front_flops"] / PEAK_FLOPS
-    memory = rec["roofline"]["front_bytes"] / HBM_BW
-    terms = dict(compute_s=compute, memory_s=memory)
+def terms_of(rec: dict, doc: dict):
+    """(terms in seconds, bottleneck name), or (None, None) without
+    peaks."""
+    if doc.get("peak_flops") is None:
+        return None, None
+    terms = dict(compute_s=rec["front_flops"] / doc["peak_flops"],
+                 memory_s=rec["roofline"]["front_bytes"] / doc["hbm_bw"])
     return terms, max(terms, key=terms.get)
 
 
-def fmt_row(rec: dict, backends) -> str:
-    t, dom = terms_of(rec)
-    cells = [f"| {rec['name']} | {rec['n']} | {t['compute_s']*1e6:.2f} | "
-             f"{t['memory_s']*1e6:.2f} | **{dom.replace('_s', '')}** | "
+def fmt_row(rec: dict, backends, doc: dict) -> str:
+    t, dom = terms_of(rec, doc)
+    if t is None:
+        roof = f"{NOT_MEASURED} | {NOT_MEASURED} | {NOT_MEASURED}"
+    else:
+        roof = (f"{t['compute_s']*1e6:.2f} | {t['memory_s']*1e6:.2f} | "
+                f"**{dom.replace('_s', '')}**")
+    cells = [f"| {rec['name']} | {rec['n']} | {roof} | "
              f"{rec['flop_ratio']:.2f} | {rec['occupancy']:.2f} "]
     for be in backends:
         e = rec["backends"].get(be)
         if e is None:
             cells.append("| — ")
             continue
-        frac = e["gflops"] * 1e9 / PEAK_FLOPS
-        cells.append(f"| {e['gflops']:.3f} ({frac*100:.2g}%) ")
+        frac = (NOT_MEASURED if t is None
+                else f"{e['gflops'] * 1e9 / doc['peak_flops'] * 100:.2g}%")
+        cells.append(f"| {e['gflops']:.3f} ({frac}) ")
     return "".join(cells) + "|"
 
 
@@ -57,7 +65,7 @@ def main(path: str = DEFAULT_PATH) -> str:
             "flops/symbolic | occupancy | "
             + " | ".join(f"{b} GF/s (of peak)" for b in backends) + " |",
             "|---" * (7 + len(backends)) + "|"]
-    rows = [fmt_row(r, backends) for r in doc["records"]]
+    rows = [fmt_row(r, backends, doc) for r in doc["records"]]
     recs = doc["records"]
     best = max(recs, key=lambda r: max(e["gflops"]
                                        for e in r["backends"].values()))

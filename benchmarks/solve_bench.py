@@ -49,7 +49,7 @@ import argparse
 import json
 import sys
 import time
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -62,12 +62,34 @@ from repro.sparse.refine import refine_solve, refine_solve_device
 from repro.sparse.schedule import build_schedule
 from repro.sparse.symbolic import symbolic_cholesky
 
-# v4-ish single-core roofline constants (same as the dry-run roofline):
-# achieved/peak ratios in the JSON are meaningful relative to each other,
-# not as absolute hardware truth on the CPU interpret backend.
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+#: Published peak rates per device kind (``jax.devices()[0].device_kind``),
+#: each with its source. The front kernels contract f32 at HIGHEST
+#: precision, i.e. several bf16 MXU passes, so the bf16 roof bounds them
+#: from above. A kind missing here is an error, never a default.
+PEAKS = {
+    "TPU v5 lite": dict(
+        flops=197e12, hbm_bw=819e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s per chip"),
+}
 BYTES_PER_FRONT_CELL = 4 * 2   # f32 workspace, read + write
+NOT_MEASURED = "not measured"
+
+
+def device_peaks() -> Optional[Dict]:
+    """The :data:`PEAKS` entry of the device JAX runs on, or None on the
+    CPU (interpret-mode kernels: no roofline share is measured there).
+    Raises for an accelerator kind the table lacks."""
+    import jax
+
+    d = jax.devices()[0]
+    if d.platform == "cpu":
+        return None
+    if d.device_kind not in PEAKS:
+        raise SystemExit(f"no published peaks for device kind "
+                         f"{d.device_kind!r} ({d.platform}); add them to "
+                         f"PEAKS with their source")
+    return PEAKS[d.device_kind]
 
 
 def make_suite(scale: float, rng: np.random.Generator) -> List:
@@ -130,7 +152,8 @@ def bench_sweeps(a, sym, sched, b, repeats: int, rhs_k: int = 8) -> Dict:
     )
 
 
-def bench_matrix(a, backends: List[str], repeats: int) -> Dict:
+def bench_matrix(a, backends: List[str], repeats: int,
+                 peaks: Optional[Dict] = None) -> Dict:
     rng = np.random.default_rng(0)
     b = rng.standard_normal(a.n)
     t0 = time.perf_counter()
@@ -150,11 +173,12 @@ def bench_matrix(a, backends: List[str], repeats: int) -> Dict:
         pad=s["pad"],
         sym_flops=sym.flops, front_flops=s["front_flops"],
         flop_ratio=s["front_flops"] / max(sym.flops, 1),
-        roofline=dict(
-            compute_s=s["front_flops"] / PEAK_FLOPS,
-            memory_s=front_bytes / HBM_BW,
-            front_bytes=front_bytes,
-        ),
+        roofline=(dict(front_bytes=front_bytes,
+                       compute_s=NOT_MEASURED, memory_s=NOT_MEASURED)
+                  if peaks is None else dict(
+                      front_bytes=front_bytes,
+                      compute_s=s["front_flops"] / peaks["flops"],
+                      memory_s=front_bytes / peaks["hbm_bw"])),
         backends={},
     )
     for backend in backends:
@@ -308,9 +332,10 @@ def main(argv=None) -> int:
     rng = np.random.default_rng(0)
     mats = make_suite(args.scale, rng)
     backends = [b.strip() for b in args.backends.split(",") if b.strip()]
+    peaks = device_peaks()
     records = []
     for a in mats:
-        rec = bench_matrix(a, backends, args.repeats)
+        rec = bench_matrix(a, backends, args.repeats, peaks)
         records.append(rec)
         line = (f"{rec['name']:>12s} n={rec['n']:>5d} nsup={rec['nsup']:>4d} "
                 f"levels={rec['nlevels']:>3d} "
@@ -322,7 +347,10 @@ def main(argv=None) -> int:
         print(line)
     doc = dict(
         bench="solve", scale=args.scale, repeats=args.repeats,
-        backends=backends, peak_flops=PEAK_FLOPS, hbm_bw=HBM_BW,
+        backends=backends,
+        peak_flops=None if peaks is None else peaks["flops"],
+        hbm_bw=None if peaks is None else peaks["hbm_bw"],
+        peak_source=NOT_MEASURED if peaks is None else peaks["source"],
         records=records,
     )
     with open(args.out, "w") as fh:

@@ -268,7 +268,8 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
     device sweeps auto-promote ``fp64`` to ``fp32_refine`` exactly like
     the device factor backends, and with ``sweep="device"`` the
     refinement loop itself runs device-resident
-    (:func:`repro.sparse.refine.refine_solve_device`). ``b`` may be a
+    (:func:`repro.sparse.refine.refine_solve_device`) on platforms whose
+    :func:`~repro.sparse.refine.residual_path` is ``"device"``. ``b`` may be a
     single RHS ``(n,)`` or a block ``(n, k)``.
 
     A :class:`RequestContext` gets ``permute``/``factor``/``solve`` spans
@@ -280,7 +281,12 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
     records the backend's ``solve.overlap_efficiency`` gauge, the sweep
     substrate (``solve.sweep.<mode>`` counters) and the refinement
     behavior (``solve.refine_iterations`` histogram plus per-count
-    ``solve.refine_iters.<i>`` counters).
+    ``solve.refine_iters.<i>`` counters, ``solve.refine.residual.<path>``
+    for where the fp64 residual ran, ``solve.refine.unconverged`` for a
+    loop that stopped above its tolerance). The result dict carries the
+    same as ``refine_iterations`` / ``refine_converged`` /
+    ``refine_residual`` (``"device"`` or ``"host"``, see
+    :func:`repro.sparse.refine.residual_path`).
     """
     assert a.data is not None, "numeric execution needs values"
     if solve_dtype not in ("fp64", "fp32", "fp32_refine"):
@@ -295,6 +301,7 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
     t_perm = time.perf_counter() - t0
 
     refine_info = None
+    refine_residual = None
     eff_dtype = solve_dtype
     eff_sweep = sweep
     fstats: dict = {}
@@ -319,7 +326,14 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
         # hoisted: one permute + fp64 cast of the RHS, outside any
         # refinement loop (the closures below only ever see residuals)
         pb = np.ascontiguousarray(b[perm], dtype=np.float64)
-        if eff_dtype == "fp32_refine" and eff_sweep == "device":
+        if eff_dtype == "fp32_refine":
+            from repro.sparse.refine import residual_path
+            # device sweeps keep the residual on the device where the
+            # platform can run the f64 matvec; host sweeps always pair
+            # with the host fp64 matvec
+            refine_residual = (residual_path() if eff_sweep == "device"
+                               else "host")
+        if refine_residual == "device":
             from repro.sparse.refine import refine_solve_device
             z, refine_info = refine_solve_device(pa, f, pb,
                                                  sweep_bs=sweep_bs, rt=rt)
@@ -374,6 +388,9 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
                 float(refine_info.iterations))
             metrics.counter(
                 f"solve.refine_iters.{min(refine_info.iterations, 8)}").inc()
+            metrics.counter(f"solve.refine.residual.{refine_residual}").inc()
+            if not refine_info.converged:
+                metrics.counter("solve.refine.unconverged").inc()
     x = np.empty_like(z)
     x[perm] = z
     resid = float(np.linalg.norm(a.matvec(x) - b)
@@ -393,5 +410,6 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
                                    else refine_info.iterations),
                 refine_converged=(None if refine_info is None
                                   else refine_info.converged),
+                refine_residual=refine_residual,
                 nnz_L=plan.nnz_L, flops=plan.predicted_flops,
                 request_id=None if ctx is None else ctx.request_id)

@@ -60,41 +60,94 @@ __all__ = ["chol_tile", "tri_inv_tile", "matmul_nt", "frontal_factor_batch",
 
 # ---------------------------------------------------------------------------
 # Shared single-tile bodies (used by both the tile kernels and the batched
-# front kernel; operate on jnp values, lower triangle authoritative)
+# front kernel; operate on jnp values, lower triangle authoritative).
+#
+# Mosaic does not lower value-level dynamic slicing, so the sequential
+# column loops below pick row/column ``j`` with iota masks and masked sums,
+# and every multi-row access at a traced offset goes through a ref with
+# ``pl.ds`` on the sublane axis. Every matmul asks for HIGHEST precision:
+# the TPU's default f32 contraction is a single bf16 pass, which would put
+# the factor's error near 1e-3 and stall the refinement built on it.
 # ---------------------------------------------------------------------------
+
+#: scoped-VMEM limit for the whole-front kernels. v5e holds 128 MiB of VMEM
+#: per core; the default 16 MiB scope stops at ~M=900 fronts, and the
+#: largest bucket the level schedule builds for the supported grids
+#: (M = P + R = 1280) needs ~40 MiB with double-buffered in/out blocks.
+VMEM_LIMIT_BYTES = 96 * 2**20
+
+_ROW_CHUNK = 128   # rows per step of the looped Schur / extend-add updates
+
+
+def _dot(a: jax.Array, b: jax.Array, contract) -> jax.Array:
+    """f32 ``dot_general`` contracting ``contract = (lhs_dims, rhs_dims)``
+    at full f32 precision."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))   # a @ bᵀ
+_NN = ((1,), (0,))   # a @ b
+_TN = ((0,), (0,))   # aᵀ @ b
+
+
+def _iotas(bs: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1))
+
 
 def _chol_block(a: jax.Array) -> jax.Array:
     """Unblocked right-looking Cholesky of one (bs, bs) f32 block value."""
     bs = a.shape[0]
-    i = jax.lax.broadcasted_iota(jnp.int32, (bs,), 0)
+    r, c = _iotas(bs)
 
     def step(j, a):
-        ajj = jax.lax.dynamic_slice(a, (j, j), (1, 1))[0, 0]
-        d = jnp.sqrt(ajj)
-        colj = jax.lax.dynamic_slice(a, (0, j), (bs, 1))[:, 0]
-        l = jnp.where(i == j, d, jnp.where(i > j, colj / d, 0.0))
-        trailing = (i[:, None] > j) & (i[None, :] > j)
-        a = a - jnp.where(trailing, l[:, None] * l[None, :], 0.0)
-        a = jax.lax.dynamic_update_slice(a, l[:, None], (0, j))
-        return a
+        colj = jnp.sum(jnp.where(c == j, a, 0.0), axis=1, keepdims=True)
+        d = jnp.sqrt(jnp.sum(jnp.where((r == j) & (c == j), a, 0.0),
+                             keepdims=True))                     # (1, 1)
+        ri = r[:, :1]
+        l = jnp.where(ri == j, d, jnp.where(ri > j, colj / d, 0.0))
+        lrow = jnp.sum(jnp.where(r == c, l, 0.0), axis=0,
+                       keepdims=True)                            # lᵀ
+        a = jnp.where((r > j) & (c > j), a - l * lrow, a)
+        return jnp.where(c == j, l, a)
 
-    return jnp.tril(jax.lax.fori_loop(0, bs, step, a))
+    return jnp.where(r >= c, jax.lax.fori_loop(0, bs, step, a), 0.0)
 
 
 def _tri_inv_block(L: jax.Array) -> jax.Array:
-    """Inverse of a lower-triangular (bs, bs) f32 block (row-by-row)."""
+    """Inverse of a lower-triangular (bs, bs) f32 block (forward
+    substitution on the identity, one pivot row per step; only the lower
+    triangle of ``L`` is read)."""
     bs = L.shape[0]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+    r, c = _iotas(bs)
 
-    def step(r, y):
-        lrow = jax.lax.dynamic_slice(L, (r, 0), (1, bs))
-        d = jax.lax.dynamic_slice(L, (r, r), (1, 1))[0, 0]
-        lrow = jnp.where(cols < r, lrow, 0.0)
-        erow = (cols == r).astype(jnp.float32)
-        yrow = (erow - jnp.dot(lrow, y, preferred_element_type=jnp.float32)) / d
-        return jax.lax.dynamic_update_slice(y, yrow, (r, 0))
+    def step(j, y):
+        lcol = jnp.sum(jnp.where(c == j, L, 0.0), axis=1, keepdims=True)
+        d = jnp.sum(jnp.where((r == j) & (c == j), L, 0.0), keepdims=True)
+        yrow = jnp.sum(jnp.where(r == j, y, 0.0), axis=0, keepdims=True) / d
+        return jnp.where(r == j, yrow, jnp.where(r > j, y - lcol * yrow, y))
 
-    return jax.lax.fori_loop(0, bs, step, jnp.zeros((bs, bs), jnp.float32))
+    return jax.lax.fori_loop(0, bs, step, (r == c).astype(jnp.float32))
+
+
+def _row_chunk(rows: int) -> int:
+    """Largest power-of-two chunk ≤ ``_ROW_CHUNK`` (and ≥ 8) dividing
+    ``rows``; ``rows`` itself when none does."""
+    t = _ROW_CHUNK
+    while t > 8 and rows % t:
+        t //= 2
+    return t if rows % t == 0 else rows
+
+
+def _row_offset(start, step: int, i):
+    """Traced sublane offset ``start + i * step``, tagged 8-aligned when it
+    is (lets Mosaic use aligned loads/stores)."""
+    off = start + i * step
+    if start % 8 == 0 and step % 8 == 0:
+        off = pl.multiple_of(off, 8)
+    return off
 
 
 # ---------------------------------------------------------------------------
@@ -153,8 +206,7 @@ def _matmul_nt_kernel(a_ref, b_ref, c_ref, o_ref, acc_ref, *,
 
     a = a_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
-    acc_ref[...] += alpha * jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    acc_ref[...] += alpha * _dot(a, b, _NT)
 
     @pl.when(ki == k_blocks - 1)
     def _finish():
@@ -197,56 +249,77 @@ def _frontal_batch_kernel(f_ref, o_ref, *, npanels: int, bs: int):
     """Blocked right-looking partial Cholesky of one (M, M) front workspace.
 
     Factors the leading ``npanels * bs`` columns; the trailing block ends up
-    holding the Schur complement. Panel loop is a static unroll (npanels is
-    a bucket constant), each panel fusing chol-tile → panel tri-solve (via
-    the tile inverse, i.e. a matmul) → rank-bs Schur update, all on the f32
-    VMEM-resident workspace. Lower triangle is authoritative throughout.
+    holding the Schur complement. The output block is the in-place VMEM
+    workspace. The panel loop is a static unroll (npanels is a bucket
+    constant); each panel fuses chol-tile → panel tri-solve (via the tile
+    inverse, i.e. a matmul) → rank-bs Schur update. The Schur update runs
+    as a loop over row chunks, so the kernel's code size grows with
+    ``M · chunk`` rather than ``M²`` (which is what keeps the compile of
+    M ≈ 1280 fronts at seconds). Lower triangle is authoritative
+    throughout.
     """
-    W = f_ref[...][0].astype(jnp.float32)
-    M = W.shape[0]
+    o_ref[...] = f_ref[...]
+    M = o_ref.shape[1]
     for t in range(npanels):
-        lo = t * bs
-        ltt = _chol_block(W[lo : lo + bs, lo : lo + bs])
-        W = jax.lax.dynamic_update_slice(W, ltt, (lo, lo))
-        below = M - lo - bs
-        if below == 0:
+        lo, hi = t * bs, (t + 1) * bs
+        ltt = _chol_block(o_ref[0, lo:hi, lo:hi].astype(jnp.float32))
+        o_ref[0, lo:hi, lo:hi] = ltt.astype(o_ref.dtype)
+        if hi == M:
             continue
-        inv = _tri_inv_block(ltt)
-        panel = W[lo + bs :, lo : lo + bs]
-        lpanel = jax.lax.dot_general(
-            panel, inv, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        W = jax.lax.dynamic_update_slice(W, lpanel, (lo + bs, lo))
-        trail = W[lo + bs :, lo + bs :] - jax.lax.dot_general(
-            lpanel, lpanel, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        W = jax.lax.dynamic_update_slice(W, trail, (lo + bs, lo + bs))
-    o_ref[...] = W[None].astype(o_ref.dtype)
+        lpanel = _dot(o_ref[0, hi:, lo:hi].astype(jnp.float32),
+                      _tri_inv_block(ltt), _NT)
+        o_ref[0, hi:, lo:hi] = lpanel.astype(o_ref.dtype)
+        T = _row_chunk(M - hi)
+
+        def schur(i, carry, lo=lo, hi=hi, T=T, lpanel=lpanel):
+            rows = pl.ds(_row_offset(hi, T, i), T)
+            upd = _dot(o_ref[0, rows, lo:hi].astype(jnp.float32), lpanel,
+                       _NT)
+            o_ref[0, rows, hi:] = (o_ref[0, rows, hi:] - upd
+                                   ).astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, (M - hi) // T, schur, 0)
 
 
-def _extend_add_kernel(dst_ref, u_ref, rows_ref, w_ref, o_ref):
+def _extend_add_kernel(dst_ref, u_ref, rows_ref, rows_t_ref, w_ref, o_ref):
     """Accumulate one child update into its parent front workspace.
 
     The scatter ``W[rows, rows] += U`` is recast as ``W += Eᵀ U E`` with
-    ``E[i, j] = (rows[i] == j)`` — two matmuls, no gather/scatter lowering
+    ``E[i, m] = (rows[i] == m)`` — matmuls, no gather/scatter lowering
     needed. Row-map entries of ``-1`` (child padding, or a padded
     contribution slot) produce an all-zero one-hot row, so they contribute
-    nothing. ``o_ref`` aliases the workspace stack; the TPU grid is
-    sequential, so contributions sorted by destination slot accumulate
-    (equal slots stay VMEM-resident between consecutive steps).
+    nothing. The product is formed per chunk of destination rows (row
+    chunk ``Eᵀ[chunk] U E``), which bounds the kernel's code size. ``o_ref``
+    aliases the workspace stack; the TPU grid is sequential and ``dst`` is
+    sorted, so each slot's contributions are one contiguous run of grid
+    steps: the run's first step loads the workspace from ``w_ref`` into the
+    resident output block, and the following steps accumulate into it.
     """
-    del w_ref  # aliased with o_ref — the accumulation target
-    U = u_ref[...][0].astype(jnp.float32)             # (R, R)
-    rows = rows_ref[...][0]                           # (R,) int32
+    c = pl.program_id(0)
+
+    @pl.when((c == 0) | (dst_ref[c] != dst_ref[jnp.maximum(c - 1, 0)]))
+    def _load():
+        o_ref[...] = w_ref[...]
+
+    U = u_ref[0].astype(jnp.float32)                     # (R, R)
     R = U.shape[0]
     M = o_ref.shape[1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (R, M), 1)
-    E = (rows[:, None] == iota).astype(jnp.float32)   # (R, M) one-hot
-    UE = jax.lax.dot_general(U, E, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    contrib = jax.lax.dot_general(E, UE, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-    o_ref[...] += contrib[None].astype(o_ref.dtype)
+    rows = rows_ref[0]                                    # (1, R) int32
+    E = (rows_t_ref[0] == jax.lax.broadcasted_iota(jnp.int32, (R, M), 1)
+         ).astype(jnp.float32)                            # (R, M) one-hot
+    T = _row_chunk(M)
+
+    def accumulate(j, carry):
+        m0 = _row_offset(0, T, j)
+        et = (jax.lax.broadcasted_iota(jnp.int32, (T, R), 0) + m0 == rows
+              ).astype(jnp.float32)                       # Eᵀ row chunk
+        upd = _dot(_dot(et, U, _NN), E, _NN)              # (T, M)
+        o_ref[0, pl.ds(m0, T), :] = (o_ref[0, pl.ds(m0, T), :] + upd
+                                     ).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, M // T, accumulate, 0)
 
 
 def extend_add_batch(w: jax.Array, u: jax.Array, dst: jax.Array,
@@ -272,7 +345,10 @@ def extend_add_batch(w: jax.Array, u: jax.Array, dst: jax.Array,
         grid=(C,),
         in_specs=[
             pl.BlockSpec((1, R, R), lambda c, dst: (c, 0, 0)),
-            pl.BlockSpec((1, R), lambda c, dst: (c, 0)),
+            # the row map twice: as a row (1, R) for the Eᵀ chunks and as
+            # a column (R, 1) for E — both full-extent trailing dims
+            pl.BlockSpec((1, 1, R), lambda c, dst: (c, 0, 0)),
+            pl.BlockSpec((1, R, 1), lambda c, dst: (c, 0, 0)),
             pl.BlockSpec((1, M, M), lambda c, dst: (dst[c], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, M, M), lambda c, dst: (dst[c], 0, 0)),
@@ -281,9 +357,11 @@ def extend_add_batch(w: jax.Array, u: jax.Array, dst: jax.Array,
         _extend_add_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, M, M), w.dtype),
-        input_output_aliases={3: 0},  # w (4th operand incl. prefetch) → out
+        input_output_aliases={4: 0},  # w (5th operand incl. prefetch) → out
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(dst, u, rows, w)
+    )(dst, u, rows.reshape(C, 1, R), rows.reshape(C, R, 1), w)
 
 
 def _tri_solve_batch_kernel(l_ref, b_ref, o_ref, *, npanels: int, bs: int,
@@ -295,51 +373,34 @@ def _tri_solve_batch_kernel(l_ref, b_ref, o_ref, *, npanels: int, bs: int,
     transpose lives in the contraction dims, not in memory). Each panel
     step inverts the (bs, bs) diagonal block via :func:`_tri_inv_block`
     and applies it as a matmul, so the only sequential work is the
-    fori_loop inside the tiny block inverse. The panel loop is a static
-    unroll (npanels is a bucket constant). Unit-diagonal padding rows in
-    the factor are decoupled identity rows: they pass their RHS entries
-    through untouched, which is what lets padded slots carry garbage
-    ("trash row" gathers) without contaminating real rows.
+    fori_loop inside the tiny block inverse. The output block is the
+    in-place solution slab; the panel loop is a static unroll (npanels is
+    a bucket constant). Unit-diagonal padding rows in the factor are
+    decoupled identity rows: they pass their RHS entries through
+    untouched, which is what lets padded slots carry garbage ("trash row"
+    gathers) without contaminating real rows.
     """
-    L = l_ref[...][0].astype(jnp.float32)           # (P, P)
-    X = b_ref[...][0].astype(jnp.float32)           # (P, K)
-    P, K = X.shape
-    if lower:
-        for t in range(npanels):
-            lo = t * bs
-            ltt = jax.lax.dynamic_slice(L, (lo, lo), (bs, bs))
-            inv = _tri_inv_block(ltt)
-            xp = jax.lax.dot_general(
-                inv, jax.lax.dynamic_slice(X, (lo, 0), (bs, K)),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            X = jax.lax.dynamic_update_slice(X, xp, (lo, 0))
-            below = P - lo - bs
-            if below:
-                pan = jax.lax.dynamic_slice(L, (lo + bs, lo), (below, bs))
-                tail = jax.lax.dynamic_slice(X, (lo + bs, 0), (below, K))
-                tail = tail - jax.lax.dot_general(
-                    pan, xp, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                X = jax.lax.dynamic_update_slice(X, tail, (lo + bs, 0))
-    else:
-        for t in range(npanels - 1, -1, -1):
-            lo = t * bs
-            ltt = jax.lax.dynamic_slice(L, (lo, lo), (bs, bs))
-            inv = _tri_inv_block(ltt)
-            rhs = jax.lax.dynamic_slice(X, (lo, 0), (bs, K))
-            below = P - lo - bs
-            if below:
-                pan = jax.lax.dynamic_slice(L, (lo + bs, lo), (below, bs))
-                tail = jax.lax.dynamic_slice(X, (lo + bs, 0), (below, K))
-                rhs = rhs - jax.lax.dot_general(
-                    pan, tail, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            xp = jax.lax.dot_general(           # (L_tt)⁻ᵀ rhs = invᵀ @ rhs
-                inv, rhs, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            X = jax.lax.dynamic_update_slice(X, xp, (lo, 0))
-    o_ref[...] = X[None].astype(o_ref.dtype)
+    o_ref[...] = b_ref[...]
+    P = l_ref.shape[1]
+    panels = range(npanels) if lower else range(npanels - 1, -1, -1)
+    for t in panels:
+        lo, hi = t * bs, (t + 1) * bs
+        inv = _tri_inv_block(l_ref[0, lo:hi, lo:hi].astype(jnp.float32))
+        if lower:
+            xp = _dot(inv, o_ref[0, lo:hi, :].astype(jnp.float32), _NN)
+            o_ref[0, lo:hi, :] = xp.astype(o_ref.dtype)
+            if hi < P:
+                o_ref[0, hi:, :] = (
+                    o_ref[0, hi:, :]
+                    - _dot(l_ref[0, hi:, lo:hi].astype(jnp.float32), xp, _NN)
+                ).astype(o_ref.dtype)
+        else:
+            rhs = o_ref[0, lo:hi, :].astype(jnp.float32)
+            if hi < P:
+                rhs = rhs - _dot(l_ref[0, hi:, lo:hi].astype(jnp.float32),
+                                 o_ref[0, hi:, :].astype(jnp.float32), _TN)
+            # (L_tt)⁻ᵀ rhs = invᵀ @ rhs
+            o_ref[0, lo:hi, :] = _dot(inv, rhs, _TN).astype(o_ref.dtype)
 
 
 def tri_solve_batch(l: jax.Array, x: jax.Array, *, bs: int,
@@ -394,5 +455,7 @@ def frontal_factor_batch(w: jax.Array, npiv: int, *, bs: int,
         in_specs=[pl.BlockSpec((1, M, M), lambda b: (b, 0, 0))],
         out_specs=pl.BlockSpec((1, M, M), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, M, M), w.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(w)

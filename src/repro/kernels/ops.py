@@ -11,6 +11,8 @@ uses :func:`attention` on real TPU — see `repro.models.layers.Attention`.
 from __future__ import annotations
 
 import functools
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Tuple
 
 import jax
@@ -27,13 +29,21 @@ from .frontal_cholesky import (chol_tile, extend_add_batch as
 from .spmv_bell import bell_spmv, csr_to_bell
 
 __all__ = ["attention", "frontal_factor", "frontal_factor_batch",
-           "frontal_factor_batch_ws", "extend_add_batch", "pick_block_size",
+           "frontal_factor_batch_ws", "extend_add_batch",
+           "extend_add_stacks", "pick_block_size", "rhs_width",
+           "compile_ahead", "f32_spec", "factor_call", "extend_add_stacks_call",
+           "sweep_calls",
            "spmv", "matmul_nt_padded", "tri_solve_batch", "rhs_tile",
            "sweep_forward", "sweep_backward"]
 
 
 def _interpret() -> bool:
     return jax.default_backend() == "cpu"
+
+
+# The sweeps' cross-front L21 products run in XLA; on a TPU its default f32
+# matmul is one bf16 pass, which would cap the sweep's accuracy near 1e-3.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -171,22 +181,81 @@ def frontal_factor_batch_ws(w: jax.Array, npiv: int, *,
     policy knob); the effective width is the largest divisor of ``npiv``
     not exceeding it. Returns the factored workspaces (see
     :func:`repro.kernels.frontal_cholesky.frontal_factor_batch`)."""
-    bs = pick_block_size(npiv, bs)
-    return _factor_batch_ws_jit(jnp.asarray(w, jnp.float32), npiv, bs,
-                                _interpret())
+    fn, args, kw = factor_call(jnp.asarray(w, jnp.float32), npiv, bs)
+    return fn(*args, **kw)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _extend_add_jit(w, u, dst, rows, interpret):
+# -- ahead-of-time compilation ------------------------------------------------
+#
+# A factorization compiles one program per distinct bucket shape — hundreds
+# for a 10⁴-row grid, at up to seconds each on the TPU compiler. The
+# ``*_call`` helpers below are the single place each wrapper turns its
+# inputs into ``(jitted fn, args, kwargs)``; given ShapeDtypeStructs they
+# describe the same call before any array exists, so the schedule's
+# programs can be compiled concurrently up front (:func:`compile_ahead`).
+
+def f32_spec(shape) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct(tuple(shape), jnp.float32)
+
+
+def compile_ahead(calls) -> None:
+    """Compile jit calls — ``(fn, args, kwargs)`` as the ``*_call``
+    helpers build them, arrays or ShapeDtypeStructs alike — concurrently
+    on the host's cores before they first run. The executables land in
+    the caches the later calls look up, so those dispatch without
+    compiling; XLA releases the GIL while it compiles."""
+    calls = list(calls)
+    if not calls:
+        return
+    workers = min(len(calls), os.cpu_count() or 1)
+    with ThreadPoolExecutor(workers, thread_name_prefix="compile") as ex:
+        futs = [ex.submit(lambda c=c: c[0].lower(*c[1], **c[2]).compile())
+                for c in calls]
+        for f in futs:
+            f.result()
+
+
+def factor_call(w, npiv: int, bs: int | None = None):
+    """:func:`frontal_factor_batch_ws`'s jit call for the (B, M, M) f32
+    stack ``w``."""
+    return (_factor_batch_ws_jit,
+            (w, npiv, pick_block_size(npiv, bs), _interpret()), {})
+
+
+def _gather_updates(stacks, srcs, order, offsets, rmax):
+    """The child update stack of one extend-add launch: ``stacks[i]`` is a
+    factored (B_i, M_i, M_i) workspace stack whose trailing block from
+    ``offsets[i]`` on holds its members' Schur updates and ``srcs[i]``
+    picks the contributing members. Each update is zero-padded to ``rmax``
+    rows/cols (the padding meets ``-1`` row-map entries, so it is inert)
+    and the concatenation is permuted by ``order`` into the launch's
+    destination-sorted order."""
+    us = []
+    for w, src, p in zip(stacks, srcs, offsets):
+        u = jnp.take(w[:, p:, p:], src, axis=0)
+        pad = rmax - u.shape[1]
+        us.append(jnp.pad(u, ((0, 0), (0, pad), (0, pad))) if pad else u)
+    return jnp.take(jnp.concatenate(us), order, axis=0)
+
+
+def _extend_add_impl(w, stacks, srcs, order, dst, rows, offsets, rmax,
+                     interpret):
+    u = _gather_updates(stacks, srcs, order, offsets, rmax)
     return _extend_add_batch_kernel(w, u, dst, rows, interpret=interpret)
 
 
+_EA_STATIC = ("offsets", "rmax", "interpret")
+_extend_add_jit = jax.jit(_extend_add_impl, static_argnames=_EA_STATIC)
 # donation realizes the kernel-level workspace aliasing as a true in-place
 # update on TPU; CPU (interpret/test) has no donation support and would
 # warn on every compile, so it gets the plain variant
-_extend_add_jit_donated = jax.jit(_extend_add_jit.__wrapped__,
-                                  static_argnames=("interpret",),
+_extend_add_jit_donated = jax.jit(_extend_add_impl,
+                                  static_argnames=_EA_STATIC,
                                   donate_argnums=(0,))
+
+
+def _extend_add_fn():
+    return _extend_add_jit if _interpret() else _extend_add_jit_donated
 
 
 def extend_add_batch(w: jax.Array, u: jax.Array, dst, rows) -> jax.Array:
@@ -196,11 +265,32 @@ def extend_add_batch(w: jax.Array, u: jax.Array, dst, rows) -> jax.Array:
     (B, M, M) at slots ``dst`` (sorted ascending) and local rows ``rows``
     (-1 = inactive). ``w`` is donated on TPU — callers must treat it as
     consumed. Calls jit-cache per (B, M, C, R) shape."""
-    interp = _interpret()
-    fn = _extend_add_jit if interp else _extend_add_jit_donated
-    return fn(jnp.asarray(w, jnp.float32), jnp.asarray(u, jnp.float32),
-              jnp.asarray(dst, jnp.int32), jnp.asarray(rows, jnp.int32),
-              interp)
+    u = jnp.asarray(u, jnp.float32)
+    idx = np.arange(u.shape[0], dtype=np.int32)
+    return extend_add_stacks(jnp.asarray(w, jnp.float32), [u], [idx], idx,
+                             np.asarray(dst, np.int32),
+                             np.asarray(rows, np.int32), offsets=(0,),
+                             rmax=u.shape[1])
+
+
+def extend_add_stacks_call(w, stacks, srcs, order, dst, rows, *, offsets,
+                           rmax: int):
+    """:func:`extend_add_stacks`'s jit call. ``w``/``stacks`` are f32,
+    ``srcs``/``order``/``dst``/``rows`` int32 (NumPy arrays at run time)."""
+    return (_extend_add_fn(),
+            (w, tuple(stacks), tuple(srcs), order, dst, rows),
+            dict(offsets=tuple(offsets), rmax=rmax, interpret=_interpret()))
+
+
+def extend_add_stacks(w: jax.Array, stacks, srcs, order, dst, rows, *,
+                      offsets, rmax: int) -> jax.Array:
+    """:func:`extend_add_batch` with the update stack gathered from the
+    children's factored workspace stacks in the same jit (see
+    :func:`_gather_updates`), so one destination bucket's extend-add is
+    one compiled program."""
+    fn, args, kw = extend_add_stacks_call(w, stacks, srcs, order, dst, rows,
+                                          offsets=offsets, rmax=rmax)
+    return fn(*args, **kw)
 
 
 def frontal_factor_batch(fs: jax.Array, npiv: int, *, bs: int | None = None
@@ -279,59 +369,89 @@ def tri_solve_batch(l: jax.Array, x: jax.Array, *, bs: int | None = None,
     return out[:, :, :K] if out.shape[2] != K else out
 
 
+def rhs_width(k: int) -> int:
+    """Column count a device sweep runs ``k`` right-hand sides at: the
+    next power of two ≥ 8. The TPU pads the minor dim to 128 lanes anyway,
+    so up to 128 columns cost the same per vreg, and a few widths mean a
+    few compiled sweep programs instead of one per RHS count."""
+    return max(8, 1 << (max(int(k), 1) - 1).bit_length())
+
+
+def _bucket_factors(w, P):
+    """(L11, L21) views of a factored (B, P + R, P + R) bucket stack. Only
+    L11's lower triangle is meaningful; the substitution kernel reads
+    nothing else."""
+    return w[:, :P, :P], w[:, P:, :P]
+
+
 @functools.partial(jax.jit, static_argnames=("bs", "kt", "interpret"))
-def _sweep_fwd_jit(x, l11, l21, piv, rest, bs, kt, interpret):
+def _sweep_fwd_jit(x, w, piv, rest, bs, kt, interpret):
     k = x.shape[1]
+    l11, l21 = _bucket_factors(w, piv.shape[1])
     xb = jnp.take(x, piv, axis=0)                         # (B, P, k)
     y = _tri_solve_batch_kernel(l11, xb, bs=bs, kt=kt, lower=True,
                                 interpret=interpret)
     x = x.at[piv.reshape(-1)].set(y.reshape(-1, k))
     if l21.shape[1]:
-        upd = jnp.einsum("brp,bpk->brk", l21, y)
+        upd = jnp.einsum("brp,bpk->brk", l21, y, precision=_HIGHEST)
         x = x.at[rest.reshape(-1)].add(-upd.reshape(-1, k))
     return x
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "kt", "interpret"))
-def _sweep_bwd_jit(x, l11, l21, piv, rest, bs, kt, interpret):
+def _sweep_bwd_jit(x, w, piv, rest, bs, kt, interpret):
     k = x.shape[1]
+    l11, l21 = _bucket_factors(w, piv.shape[1])
     rhs = jnp.take(x, piv, axis=0)                        # (B, P, k)
     if l21.shape[1]:
         xr = jnp.take(x, rest, axis=0)                    # (B, R, k)
-        rhs = rhs - jnp.einsum("brp,brk->bpk", l21, xr)
+        rhs = rhs - jnp.einsum("brp,brk->bpk", l21, xr, precision=_HIGHEST)
     y = _tri_solve_batch_kernel(l11, rhs, bs=bs, kt=kt, lower=False,
                                 interpret=interpret)
     return x.at[piv.reshape(-1)].set(y.reshape(-1, k))
 
 
-def sweep_forward(x: jax.Array, l11: jax.Array, l21: jax.Array,
-                  piv: jax.Array, rest: jax.Array, *, bs: int | None = None,
+def _sweep_call(fwd: bool, x, w, piv, rest, bs, rt):
+    return (_sweep_fwd_jit if fwd else _sweep_bwd_jit,
+            (x, w, piv, rest, pick_block_size(piv.shape[1], bs),
+             rhs_tile(x.shape[1], rt), _interpret()), {})
+
+
+def sweep_calls(x, w, piv, rest, *, bs: int | None = None,
+                rt: int | None = None) -> list:
+    """The forward and backward sweep jit calls of one level-bucket."""
+    return [_sweep_call(fwd, x, w, piv, rest, bs, rt)
+            for fwd in (True, False)]
+
+
+def sweep_forward(x: jax.Array, w: jax.Array, piv: jax.Array,
+                  rest: jax.Array, *, bs: int | None = None,
                   rt: int | None = None) -> jax.Array:
     """One level-bucket's forward-substitution step on a device-resident
     RHS block.
 
     ``x``: (n + 1, K) f32 — the solution-in-progress with a trailing
     "trash row" that every padded index points at (garbage in, garbage
-    confined: identity pad rows in ``l11`` and zero pad rows/cols in
-    ``l21`` keep it inert). Gathers the bucket's pivot rows, runs the
-    batched :func:`tri_solve_batch` lower sweep, scatters the solved
-    pivots back, and scatter-subtracts the ``L21 y`` cross-front updates —
-    all inside one jit, dispatched asynchronously.
+    confined: identity pad rows in L11 and zero pad rows/cols in L21 keep
+    it inert). ``w``: the bucket's factored (B, P + R, P + R) stack, L11 in
+    its leading block and L21 below it (``P = piv.shape[1]``). Gathers the
+    bucket's pivot rows, runs the batched :func:`tri_solve_batch` lower
+    sweep, scatters the solved pivots back, and scatter-subtracts the
+    ``L21 y`` cross-front updates — all inside one jit, dispatched
+    asynchronously.
     """
-    return _sweep_fwd_jit(x, l11, l21, piv, rest,
-                          pick_block_size(l11.shape[1], bs),
-                          rhs_tile(x.shape[1], rt), _interpret())
+    fn, args, kw = _sweep_call(True, x, w, piv, rest, bs, rt)
+    return fn(*args, **kw)
 
 
-def sweep_backward(x: jax.Array, l11: jax.Array, l21: jax.Array,
-                   piv: jax.Array, rest: jax.Array, *, bs: int | None = None,
+def sweep_backward(x: jax.Array, w: jax.Array, piv: jax.Array,
+                   rest: jax.Array, *, bs: int | None = None,
                    rt: int | None = None) -> jax.Array:
     """One level-bucket's backward-substitution step (``Lᵀ x = y``):
     gathers pivot and update rows, subtracts the ``L21ᵀ`` coupling, runs
     the batched upper sweep, and scatters the solved pivots back."""
-    return _sweep_bwd_jit(x, l11, l21, piv, rest,
-                          pick_block_size(l11.shape[1], bs),
-                          rhs_tile(x.shape[1], rt), _interpret())
+    fn, args, kw = _sweep_call(False, x, w, piv, rest, bs, rt)
+    return fn(*args, **kw)
 
 
 def spmv(indptr: np.ndarray, indices: np.ndarray, data: np.ndarray,

@@ -5,8 +5,11 @@ ELL layout — every block-row holds exactly ``max_k`` blocks (zero-padded) and
 a scalar-prefetched index vector names each block's column block. Scalar
 prefetch feeds the x-block index_map, so the gather happens in the pipeline's
 address generation rather than as vector gather ops (the standard Pallas TPU
-sparse idiom). Used for on-device iterative refinement and batched feature
-extraction in the serving example.
+sparse idiom). The index table is prefetched flat: SMEM pads a 2-D table's
+minor dim to 128 words, which at ``max_k`` ≈ 9 inflates a 22k-row matrix's
+table past SMEM's 1 MiB. f32 only — the TPU has no f64 vector unit, so the
+refinement's f64 residual is a plain-XLA matvec
+(:mod:`repro.sparse.refine`).
 """
 from __future__ import annotations
 
@@ -58,21 +61,19 @@ def _bell_kernel(idx_ref, blocks_ref, x_ref, o_ref, *, max_k: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    # Accumulate in the output dtype: f32 normally, f64 when the caller runs
-    # under the x64 context (device-resident refinement residuals).
-    acc = jnp.float64 if o_ref.dtype == jnp.float64 else jnp.float32
-    blk = blocks_ref[0, 0].astype(acc)                # (bs, bs)
-    xb = x_ref[...].astype(acc)                       # (bs, kk)
-    o_ref[...] += jnp.dot(blk, xb, preferred_element_type=acc
+    blk = blocks_ref[0, 0].astype(jnp.float32)        # (bs, bs)
+    xb = x_ref[...].astype(jnp.float32)               # (bs, kk)
+    o_ref[...] += jnp.dot(blk, xb, precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32
                           ).astype(o_ref.dtype)
 
 
 def bell_spmv(blocks: jax.Array, idx: jax.Array, x: jax.Array, *,
               interpret: bool = False) -> jax.Array:
-    """y = A @ x with A in block-ELL form.
+    """y = A @ x with A in block-ELL form (f32).
 
     x: ``(n_pad,)`` or an RHS block ``(n_pad, k)``; the result matches x's
-    shape and dtype (fp64 in/out when running under ``enable_x64``).
+    shape.
     """
     nrb, max_k, bs, _ = blocks.shape
     single = x.ndim == 1
@@ -83,7 +84,8 @@ def bell_spmv(blocks: jax.Array, idx: jax.Array, x: jax.Array, *,
         grid=(nrb, max_k),
         in_specs=[
             pl.BlockSpec((1, 1, bs, bs), lambda r, k, idx_ref: (r, k, 0, 0)),
-            pl.BlockSpec((bs, kk), lambda r, k, idx_ref: (idx_ref[r, k], 0)),
+            pl.BlockSpec((bs, kk),
+                         lambda r, k, idx_ref: (idx_ref[r * max_k + k], 0)),
         ],
         out_specs=pl.BlockSpec((bs, kk), lambda r, k, idx_ref: (r, 0)),
         scratch_shapes=[],
@@ -91,7 +93,7 @@ def bell_spmv(blocks: jax.Array, idx: jax.Array, x: jax.Array, *,
     out = pl.pallas_call(
         functools.partial(_bell_kernel, max_k=max_k),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nrb * bs, kk), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((nrb * bs, kk), jnp.float32),
         interpret=interpret,
-    )(idx, blocks, x2)
+    )(jnp.reshape(idx, (-1,)), blocks, x2)
     return out[:, 0] if single else out

@@ -54,6 +54,7 @@ learns why.
 from __future__ import annotations
 
 import argparse
+import os
 import pickle
 import socket
 import socketserver
@@ -394,8 +395,11 @@ class PlanRPCServer:
 class PlanRPCClient:
     """Blocking client for :class:`PlanRPCServer` (one socket, in-order).
 
-    Usable from any process with network reach to the server — no jax, no
-    trained model, no cache directory needed on the client side::
+    Usable from any process with network reach to the server — no trained
+    model, no cache directory and no accelerator needed on the client side.
+    Unpickling a plan imports ``repro`` and therefore ``jax``, so a client
+    on the server's host runs with ``JAX_PLATFORMS=cpu`` (the chip belongs
+    to the server process)::
 
         with PlanRPCClient("127.0.0.1", port) as c:
             plan = c.plan(matrix)          # ExecutionPlan, cold or warm
@@ -619,8 +623,12 @@ def _smoke(server: PlanRPCServer) -> int:
         "                  'algorithm': plan_cold.algorithm,\n"
         "                  'warm_hits': stats['warm_hits']}))\n"
     )
+    # the server process holds the accelerator; unpickling plans imports
+    # repro (and with it jax) in the child, so pin the child to the CPU
+    # backend or it would try to claim the same chip
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", child, str(server.port)],
-                       capture_output=True, text=True, timeout=300)
+                       capture_output=True, text=True, timeout=300, env=env)
     if r.returncode != 0:
         print(f"[rpc-smoke] FAIL\n{r.stdout}\n{r.stderr}")
         return 1
@@ -632,4 +640,8 @@ def _smoke(server: PlanRPCServer) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache(os.path.join(os.path.dirname(__file__),
+                                         "..", "..", ".."))
     main()

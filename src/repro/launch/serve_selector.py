@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import os
 import time
 from typing import Dict, List, Sequence
 
@@ -233,4 +234,8 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache(os.path.join(os.path.dirname(__file__),
+                                         "..", "..", ".."))
     main()

@@ -50,7 +50,7 @@ import numpy as np
 from repro.core.labeling import LabeledDataset
 from repro.engine.registry import get_feature_set
 from repro.sparse.csr import CSRMatrix, permute_symmetric
-from repro.sparse.multifrontal import factor_and_solve_timed
+from repro.sparse.multifrontal import DEVICE_BACKENDS, factor_and_solve_timed
 from repro.sparse.reorder import LABEL_ALGORITHMS, get_reordering
 
 __all__ = ["CampaignConfig", "CampaignResult", "run_campaign",
@@ -406,6 +406,13 @@ def main(argv=None) -> int:
     campaign_id = (args.campaign_id
                    or f"c{args.count}_s{args.seed}_x{args.scale:g}")
 
+    if args.processes > 1 and args.backend in DEVICE_BACKENDS:
+        raise SystemExit(
+            f"--processes {args.processes} with --backend {args.backend}: "
+            "every shard process would claim the accelerator, and a chip "
+            "belongs to one process at a time (the second one fails or "
+            "hangs at its first kernel). Label a device backend in one "
+            "process (--processes 0 or 1), or fan out with --backend numpy.")
     if args.processes > 0:
         base = ["--campaign-id", campaign_id,
                 "--labels-dir", args.labels_dir,

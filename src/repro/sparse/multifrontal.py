@@ -387,6 +387,74 @@ def _pad_pow2(n: int) -> int:
     return 1 << (int(n) - 1).bit_length() if n > 1 else 1
 
 
+@dataclasses.dataclass
+class _ExtendAddPlan:
+    """One destination bucket's extend-add launch, from the schedule
+    alone: every child contribution, from every source bucket, merged in
+    destination-slot order (the kernel's sequential accumulation
+    contract), updates padded to the largest source ``R`` — so the launch
+    (and its compile) is per destination bucket, not per (source,
+    destination) pair."""
+
+    sources: List[Tuple[int, int]]   # (level, bucket) of each source stack
+    src_ids: List[np.ndarray]        # contributing slots per source, int32
+    offsets: Tuple[int, ...]         # each source's pivot dim P
+    order: np.ndarray                # (Cp,) concatenation → dst order
+    dst: np.ndarray                  # (Cp,) destination slots, ascending
+    rows: np.ndarray                 # (Cp, rmax) row maps, −1 inactive
+    rmax: int
+
+
+def _extend_add_plan(schedule: LevelSchedule, srcs) -> _ExtendAddPlan:
+    """``srcs``: the sorted ``[((src_level, src_bucket), contribs), ...]``
+    of :func:`_route_contributions` for one destination bucket."""
+    rmax = max(schedule.buckets[sli][sbj].R for (sli, sbj), _ in srcs)
+    src_ids, dst, rows = [], [], []
+    for _, contribs in srcs:
+        src_ids.append(np.array([c[0] for c in contribs], np.int32))
+        for _, d, rowmap in contribs:
+            dst.append(d)
+            rows.append(np.pad(rowmap, (0, rmax - rowmap.size),
+                               constant_values=-1))
+    order = np.argsort(np.asarray(dst), kind="stable").astype(np.int32)
+    dst = np.asarray(dst, np.int32)[order]
+    rows = np.stack(rows)[order]
+    # pad the contribution count to a power of two so jit shapes stay
+    # bounded; pads are inert (rowmap −1 ⇒ all-zero one-hot ⇒ zero
+    # contribution) and keep dst sorted
+    C, Cp = len(order), _pad_pow2(len(order))
+    if Cp != C:
+        order = np.concatenate([order, np.zeros(Cp - C, np.int32)])
+        dst = np.concatenate([dst, np.full(Cp - C, dst[-1], np.int32)])
+        rows = np.concatenate([rows, np.full((Cp - C, rmax), -1, np.int32)])
+    return _ExtendAddPlan(
+        [key for key, _ in srcs], src_ids,
+        tuple(schedule.buckets[sli][sbj].P for (sli, sbj), _ in srcs),
+        order, dst, rows, rmax)
+
+
+def _pipelined_calls(ops, schedule: LevelSchedule, ea_plans: dict,
+                     bs: Optional[int]) -> list:
+    """Every kernel program :func:`_factor_pipelined` will run, as
+    ShapeDtypeStruct calls for :func:`repro.kernels.ops.compile_ahead`."""
+    def stack(li, bj):
+        b = schedule.buckets[li][bj]
+        return ops.f32_spec((len(b.members), b.M, b.M))
+
+    calls = []
+    for li in range(schedule.nlevels):
+        for bj, bucket in enumerate(schedule.buckets[li]):
+            w = stack(li, bj)
+            ea = ea_plans.get((li, bj))
+            if ea is not None:
+                calls.append(ops.extend_add_stacks_call(
+                    w, [stack(*k) for k in ea.sources], ea.src_ids,
+                    ea.order, ea.dst, ea.rows, offsets=ea.offsets,
+                    rmax=ea.rmax))
+            calls.append(ops.factor_call(w, bucket.P, bs))
+    return calls
+
+
 def _factor_pipelined(a: CSRMatrix, schedule: LevelSchedule,
                       bs: Optional[int] = None, ctx=None
                       ) -> Tuple[List[_Front], dict, dict]:
@@ -405,7 +473,9 @@ def _factor_pipelined(a: CSRMatrix, schedule: LevelSchedule,
     host-side triangular sweeps. The factored device stacks are *also*
     returned (third element) and retained on the factor: ``sweep="device"``
     slices L11/L21 straight out of them, so device sweeps never re-upload
-    the factors the drain just pulled down.
+    the factors the drain just pulled down. Before the first dispatch,
+    every kernel program of the schedule is compiled concurrently
+    (:func:`repro.kernels.ops.compile_ahead`; ``t_factor_compile``).
     """
     import jax.numpy as jnp
 
@@ -414,7 +484,11 @@ def _factor_pipelined(a: CSRMatrix, schedule: LevelSchedule,
     pc = time.perf_counter
     nsup = schedule.nsup
     fronts: List[Optional[_Front]] = [None] * nsup
-    routes = _route_contributions(schedule)
+    ea_plans = {key: _extend_add_plan(schedule, sorted(srcs.items()))
+                for key, srcs in _route_contributions(schedule).items()}
+    t0 = pc()
+    ops.compile_ahead(_pipelined_calls(ops, schedule, ea_plans, bs))
+    t_compile = pc() - t0
     dev: dict = {}             # (level, bucket) -> factored device stack
     t_asm = t_disp = t_sync = 0.0
     for li in range(schedule.nlevels):
@@ -426,29 +500,11 @@ def _factor_pipelined(a: CSRMatrix, schedule: LevelSchedule,
             t_asm += pc() - t0
             t0 = pc()
             w = jnp.asarray(W)
-            for (sli, sbj), contribs in sorted(
-                    routes.get((li, bj), {}).items()):
-                # sorted destination slots: the kernel's sequential
-                # accumulation contract (equal slots stay VMEM-resident)
-                contribs.sort(key=lambda c: c[1])
-                src = np.array([c[0] for c in contribs], dtype=np.int32)
-                dst = np.array([c[1] for c in contribs], dtype=np.int32)
-                rows = np.stack([c[2] for c in contribs])
-                # pad the contribution count to a power of two so jit
-                # shapes stay bounded; pads are inert (rowmap −1 ⇒ all-zero
-                # one-hot ⇒ zero contribution) and keep dst sorted
-                C, Cp = len(contribs), _pad_pow2(len(contribs))
-                if Cp != C:
-                    src = np.concatenate([src, np.zeros(Cp - C, np.int32)])
-                    dst = np.concatenate(
-                        [dst, np.full(Cp - C, dst[-1], np.int32)])
-                    rows = np.concatenate(
-                        [rows, np.full((Cp - C, rows.shape[1]), -1,
-                                       np.int32)])
-                P_src = schedule.buckets[sli][sbj].P
-                u = jnp.take(dev[(sli, sbj)][:, P_src:, P_src:],
-                             jnp.asarray(src), axis=0)
-                w = ops.extend_add_batch(w, u, dst, rows)
+            ea = ea_plans.get((li, bj))
+            if ea is not None:
+                w = ops.extend_add_stacks(
+                    w, [dev[k] for k in ea.sources], ea.src_ids, ea.order,
+                    ea.dst, ea.rows, offsets=ea.offsets, rmax=ea.rmax)
             dev[(li, bj)] = ops.frontal_factor_batch_ws(w, bucket.P, bs=bs)
             t_disp += pc() - t0
     # drain: the only host↔device sync — by now the host has assembled and
@@ -468,7 +524,9 @@ def _factor_pipelined(a: CSRMatrix, schedule: LevelSchedule,
                 L21 = Wf[bi, P : P + fp.nrest, : fp.npiv]
                 fronts[k] = _Front((fp.c0, fp.c1), fp.rows, L11, L21)
             t_asm += pc() - t0
-    return fronts, _overlap_timings(t_asm, t_disp, t_sync), dev  # type: ignore[return-value]
+    timings = _overlap_timings(t_asm, t_disp, t_sync)
+    timings["t_factor_compile"] = t_compile
+    return fronts, timings, dev  # type: ignore[return-value]
 
 
 # ---------------------------------------------------------------------------
@@ -602,8 +660,7 @@ class _DeviceSweepGroup:
     device: identity pad rows in L11 and zero pad rows/cols in L21 keep
     whatever garbage the trash row holds out of every real entry."""
 
-    L11: object            # (B, P, P) f32 device, unit-diag padded
-    L21: object            # (B, R, P) f32 device
+    W: object              # (B, P + R, P + R) f32 device: L11 over L21
     piv: object            # (B, P) int32 device, pads -> n
     rest: object           # (B, R) int32 device, pads -> n
 
@@ -611,6 +668,8 @@ class _DeviceSweepGroup:
 @dataclasses.dataclass
 class _DeviceSweeps:
     levels: List[List[_DeviceSweepGroup]]
+    # (K, sweep_bs, rt) settings whose sweep programs are compiled
+    compiled: set = dataclasses.field(default_factory=set)
 
 
 def _bucket_indices(sched: LevelSchedule, bucket, n: int
@@ -633,39 +692,35 @@ def _build_device_sweeps(f: MultifrontalFactor) -> _DeviceSweeps:
 
     After a ``pipelined`` factorization the factored workspace stacks are
     still device-resident (``f._device_stacks``) and already in the padded
-    bucket layout — L11/L21 are sliced straight out of them (the identity
-    pivot pads factored to unit-diagonal rows, update-row pads to zero
-    rows, exactly the inert padding the sweeps need). Any other backend
-    uploads its host fronts once; repeated solves reuse the cached stacks.
+    bucket layout — the sweeps read L11/L21 straight out of them (the
+    identity pivot pads factored to unit-diagonal rows, update-row pads to
+    zero rows, exactly the inert padding the sweeps need). Any other
+    backend packs its host fronts into the same layout and uploads them
+    once; repeated solves reuse the cached stacks.
     """
     import jax.numpy as jnp
 
     sched = f.schedule
     assert sched is not None
-    n = f.n
-    levels: List[List[_DeviceSweepGroup]] = []
-    if f._device_stacks is not None:
-        for li in range(sched.nlevels):
-            groups: List[_DeviceSweepGroup] = []
-            for bj, bucket in enumerate(sched.buckets[li]):
-                W = f._device_stacks[(li, bj)]
-                P = bucket.P
-                piv, rest = _bucket_indices(sched, bucket, n)
-                groups.append(_DeviceSweepGroup(
-                    jnp.tril(W[:, :P, :P]), W[:, P:, :P],
-                    jnp.asarray(piv), jnp.asarray(rest)))
-            levels.append(groups)
-        return _DeviceSweeps(levels)
-    if f._sweeps is None:
+    if f._device_stacks is None and f._sweeps is None:
         f._sweeps = _build_sweeps(f)
-    for li, host_groups in enumerate(f._sweeps.levels):
-        groups = []
-        for bj, g in enumerate(host_groups):
-            piv, rest = _bucket_indices(sched, sched.buckets[li][bj], n)
-            groups.append(_DeviceSweepGroup(
-                jnp.asarray(g.L11, jnp.float32),
-                jnp.asarray(g.L21, jnp.float32),
-                jnp.asarray(piv), jnp.asarray(rest)))
+    levels: List[List[_DeviceSweepGroup]] = []
+    for li in range(sched.nlevels):
+        groups: List[_DeviceSweepGroup] = []
+        for bj, bucket in enumerate(sched.buckets[li]):
+            if f._device_stacks is not None:
+                W = f._device_stacks[(li, bj)]
+            else:
+                g = f._sweeps.levels[li][bj]
+                P = bucket.P
+                Wh = np.zeros((len(bucket.members), bucket.M, bucket.M),
+                              np.float32)
+                Wh[:, :P, :P] = g.L11
+                Wh[:, P:, :P] = g.L21
+                W = jnp.asarray(Wh)
+            piv, rest = _bucket_indices(sched, bucket, f.n)
+            groups.append(_DeviceSweepGroup(W, jnp.asarray(piv),
+                                            jnp.asarray(rest)))
         levels.append(groups)
     return _DeviceSweeps(levels)
 
@@ -681,14 +736,20 @@ def _device_sweep_passes(f: MultifrontalFactor, x, *,
     if f._dev_sweeps is None:
         f._dev_sweeps = _build_device_sweeps(f)
     sw = f._dev_sweeps
+    key = (x.shape[1], sweep_bs, rt)
+    if key not in sw.compiled:
+        ops.compile_ahead(c for groups in sw.levels for g in groups
+                          for c in ops.sweep_calls(x, g.W, g.piv, g.rest,
+                                                   bs=sweep_bs, rt=rt))
+        sw.compiled.add(key)
     for groups in sw.levels:
         for g in groups:
-            x = ops.sweep_forward(x, g.L11, g.L21, g.piv, g.rest,
-                                  bs=sweep_bs, rt=rt)
+            x = ops.sweep_forward(x, g.W, g.piv, g.rest, bs=sweep_bs,
+                                  rt=rt)
     for groups in reversed(sw.levels):
         for g in groups:
-            x = ops.sweep_backward(x, g.L11, g.L21, g.piv, g.rest,
-                                   bs=sweep_bs, rt=rt)
+            x = ops.sweep_backward(x, g.W, g.piv, g.rest, bs=sweep_bs,
+                                   rt=rt)
     return x
 
 
@@ -699,12 +760,12 @@ def _solve_device(f: MultifrontalFactor, b2: np.ndarray, *,
     async dispatch per level-bucket, one sync to fetch the solution."""
     import jax.numpy as jnp
 
+    from repro.kernels.ops import rhs_width
+
     n, k = b2.shape
-    kt = k if rt is None else max(1, min(int(rt), k))
-    kp = -(-k // kt) * kt          # pad K so the RHS-tile grid divides it
-    xb = np.zeros((n + 1, kp), dtype=np.float32)
+    xb = np.zeros((n + 1, rhs_width(k)), dtype=np.float32)
     xb[:n, :k] = b2
-    x = _device_sweep_passes(f, jnp.asarray(xb), sweep_bs=sweep_bs, rt=kt)
+    x = _device_sweep_passes(f, jnp.asarray(xb), sweep_bs=sweep_bs, rt=rt)
     return np.asarray(x[:n, :k], dtype=np.float64)
 
 

@@ -19,11 +19,13 @@ Two drivers share those stopping rules:
   ``solve`` closures (any backend, any sweep mode).
 * :func:`refine_solve_device` — the device-resident loop for
   ``sweep="device"``: x, r, and the factor stacks stay in device memory,
-  the fp64 residual matvec runs through the block-ELL SpMV kernel
-  (:mod:`repro.kernels.spmv_bell`), and the only host↔device traffic per
-  iteration is the residual-norm scalar. fp64 on device needs the x64
-  context (CPU interpret / CI); on an f64-less accelerator the residual
-  falls back to f32 and the loop simply stalls out earlier.
+  the fp64 residual is a plain-XLA CSR matvec traced under
+  ``jax.enable_x64`` (the TPU has no f64 vector unit, so no Pallas kernel
+  can compute it; XLA emulates f64 there), and the only host↔device
+  traffic per iteration is the residual-norm scalar.
+
+:func:`residual_path` says which of the two serves ``sweep="device"`` on
+the platform at hand (``jax.devices()[0].platform``); callers record it.
 
 This is what makes the fp32 ``batched``/``pallas`` factorization backends of
 :mod:`repro.sparse.multifrontal` usable as drop-in replacements for the fp64
@@ -39,7 +41,7 @@ from typing import Callable, List, Optional, Tuple
 import numpy as np
 
 __all__ = ["RefineInfo", "refine_solve", "refine_solve_device",
-           "DEFAULT_TOL"]
+           "residual_path", "DEFAULT_TOL"]
 
 DEFAULT_TOL = 1e-12
 _STALL_FACTOR = 0.5   # require ≥ 2× residual reduction per sweep to continue
@@ -115,30 +117,38 @@ def refine_solve(matvec: Callable[[np.ndarray], np.ndarray],
         iters += 1
 
 
-def _jax_x64():
-    """The ``enable_x64`` context manager when this jax build has it, else
-    a no-op context (residual math then runs in f32 and the stall guard
-    ends the loop at the f32 floor)."""
-    try:
-        from jax.experimental import enable_x64
-        return enable_x64()
-    except ImportError:  # pragma: no cover - old jax
-        import contextlib
-        return contextlib.nullcontext()
+#: where the fp64 residual of a ``sweep="device"`` refinement runs, per JAX
+#: platform: ``"device"`` keeps x/r on the device (:func:`refine_solve_device`,
+#: XLA f64 matvec), ``"host"`` runs :func:`refine_solve` with a NumPy fp64
+#: matvec around the device sweeps. Platforms not listed take the host loop.
+#: (On a TPU v5e, XLA's emulated f64 residual norm matched NumPy's to
+#: 3.5e-15 relative.)
+_RESIDUAL_PATHS = {"cpu": "device", "tpu": "device"}
+
+
+def residual_path() -> str:
+    """``"device"`` or ``"host"``: where this platform computes the fp64
+    residual of a device-swept refinement."""
+    import jax
+
+    return _RESIDUAL_PATHS.get(jax.devices()[0].platform, "host")
 
 
 @functools.cache
 def _residual_dev_fn():
-    """jit'd device residual step: r = b − A x (block-ELL SpMV) and ‖r‖."""
+    """jit'd device residual step: r = b − A x for a CSR ``A`` (f64 segment
+    sum over the row ids), returned as the f32 correction RHS together with
+    the f64 norm ‖r‖. Trace and call it under ``jax.enable_x64``."""
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.spmv_bell import bell_spmv
-
-    @functools.partial(jax.jit, static_argnames=("interpret",))
-    def step(blocks, idx, x, bp, interpret):
-        r = bp - bell_spmv(blocks, idx, x, interpret=interpret)
-        return r, jnp.linalg.norm(r)
+    @jax.jit
+    def step(data, rows, cols, x, b):
+        ax = jax.ops.segment_sum(data[:, None] * x[cols], rows,
+                                 num_segments=x.shape[0],
+                                 indices_are_sorted=True)
+        r = b - ax
+        return r.astype(jnp.float32), jnp.sqrt(jnp.sum(r * r))
 
     return step
 
@@ -146,26 +156,26 @@ def _residual_dev_fn():
 def refine_solve_device(a, f, b: np.ndarray, *,
                         tol: float = DEFAULT_TOL, max_iter: int = 10,
                         sweep_bs: Optional[int] = None,
-                        rt: Optional[int] = None,
-                        spmv_bs: int = 8) -> tuple[np.ndarray, RefineInfo]:
+                        rt: Optional[int] = None
+                        ) -> tuple[np.ndarray, RefineInfo]:
     """Device-resident refinement for the ``sweep="device"`` solve path.
 
     ``a`` is the (permuted) fp64 :class:`repro.sparse.csr.CSRMatrix`, ``f``
     the schedule-carrying :class:`~repro.sparse.multifrontal.
     MultifrontalFactor`. The solution and residual live on device for the
     whole loop: the correction solve is the batched-Pallas sweep pass on
-    the resident factor stacks, the residual matvec is the block-ELL SpMV
-    kernel over fp64 blocks (converted from CSR once), and the only
-    per-iteration host↔device traffic is the residual-norm scalar — the
-    ``float()`` that also serves as the sync point for the level-bucket
-    dispatches queued by the sweep. Stopping rules (tol / max_iter /
-    stall) are shared with :func:`refine_solve`. ``b``: ``(n,)`` or
-    ``(n, k)``; returns ``(x fp64 host, RefineInfo)``.
+    the resident factor stacks (f32, traced outside the x64 context so the
+    kernels stay 32-bit), the residual is an fp64 XLA CSR matvec under
+    ``jax.enable_x64``, and the only per-iteration host↔device traffic is
+    the residual-norm scalar — the ``float()`` that also serves as the sync
+    point for the level-bucket dispatches queued by the sweep. Stopping
+    rules (tol / max_iter / stall) are shared with :func:`refine_solve`.
+    ``b``: ``(n,)`` or ``(n, k)``; returns ``(x fp64 host, RefineInfo)``.
     """
+    import jax
     import jax.numpy as jnp
 
-    from repro.kernels.ops import _interpret
-    from repro.kernels.spmv_bell import csr_to_bell
+    from repro.kernels.ops import rhs_width
     from repro.sparse.multifrontal import _device_sweep_passes
 
     pc = time.perf_counter
@@ -176,41 +186,44 @@ def refine_solve_device(a, f, b: np.ndarray, *,
     nb = float(np.linalg.norm(b2))
     if nb == 0.0:
         return np.zeros_like(b), RefineInfo(0, [0.0], True)
-    blocks, idx, npad = csr_to_bell(a.indptr, a.indices, a.data, n,
-                                    bs=spmv_bs)
-    interp = _interpret()
     residual_step = _residual_dev_fn()
+    rows = np.repeat(np.arange(n, dtype=np.int32), np.diff(a.indptr))
 
     def sweep(r32):
         """f32 sweep pass on a device (n, k) block → device (n, k) f32."""
-        x = jnp.zeros((n + 1, k), jnp.float32).at[:n].set(r32)
-        return _device_sweep_passes(f, x, sweep_bs=sweep_bs, rt=rt)[:n]
+        x = jnp.zeros((n + 1, rhs_width(k)), jnp.float32).at[:n, :k].set(r32)
+        return _device_sweep_passes(f, x, sweep_bs=sweep_bs, rt=rt)[:n, :k]
 
-    with _jax_x64():
-        blocks_d = jnp.asarray(blocks)                   # fp64 ELL blocks
-        idx_d = jnp.asarray(idx)
-        bp = jnp.zeros((npad, k)).at[:n].set(jnp.asarray(b2))
+    with jax.enable_x64(True):
+        data_d = jnp.asarray(a.data, jnp.float64)
+        rows_d = jnp.asarray(rows)
+        cols_d = jnp.asarray(a.indices, jnp.int32)
+        b_d = jnp.asarray(b2, jnp.float64)
+    t0 = pc()
+    dx = sweep(jnp.asarray(b2, jnp.float32))
+    with jax.enable_x64(True):
+        x = dx.astype(jnp.float64)
+    t_sweep = pc() - t0
+    residuals: List[float] = []
+    iters = 0
+    t_res = 0.0
+    while True:
         t0 = pc()
-        dx = sweep(jnp.asarray(b2.astype(np.float32)))
-        x = jnp.zeros((npad, k)).at[:n].set(dx.astype(bp.dtype))
-        t_sweep = pc() - t0
-        residuals: List[float] = []
-        iters = 0
-        t_res = 0.0
-        while True:
-            t0 = pc()
-            r, nrm = residual_step(blocks_d, idx_d, x, bp, interp)
+        with jax.enable_x64(True):
+            r32, nrm = residual_step(data_d, rows_d, cols_d, x, b_d)
             rel = float(nrm) / nb       # the one per-iteration scalar sync
-            t_res += pc() - t0
-            residuals.append(rel)
-            stop, ok = _should_stop(residuals, tol, iters, max_iter)
-            if stop:
-                break
-            t0 = pc()
-            dx = sweep(r[:n].astype(jnp.float32))
-            x = x.at[:n].add(dx.astype(bp.dtype))
-            t_sweep += pc() - t0
-            iters += 1
-        out = np.asarray(x[:n], dtype=np.float64)
+        t_res += pc() - t0
+        residuals.append(rel)
+        stop, ok = _should_stop(residuals, tol, iters, max_iter)
+        if stop:
+            break
+        t0 = pc()
+        dx = sweep(r32)
+        with jax.enable_x64(True):
+            x = x + dx.astype(jnp.float64)
+        t_sweep += pc() - t0
+        iters += 1
+    with jax.enable_x64(True):
+        out = np.asarray(x, dtype=np.float64)
     return (out[:, 0] if single else out,
             RefineInfo(iters, residuals, ok, t_sweep, t_res))

@@ -95,6 +95,18 @@ def test_campaign_shards_partition_and_assemble(tmp_path):
     assert (ds.labels == ds.times.argmin(axis=1)).all()
 
 
+@pytest.mark.parametrize("backend", ["pallas", "batched", "pipelined"])
+def test_campaign_cli_refuses_processes_on_device_backend(tmp_path, backend):
+    """Each shard process would claim the accelerator: refused up front,
+    before any child is spawned."""
+    from repro.lifecycle.campaign import main
+
+    with pytest.raises(SystemExit, match="one process at a time"):
+        main(["--processes", "2", "--backend", backend,
+              "--labels-dir", str(tmp_path), "--out", ""])
+    assert not os.listdir(tmp_path)
+
+
 def test_assemble_incomplete_campaign_raises(tmp_path):
     mats = tiny_suite()
     run_campaign(mats, campaign_cfg(tmp_path, max_cells=3))

@@ -260,3 +260,42 @@ def test_engine_config_accepts_pipelined_and_autotune_knobs(tmp_path):
     assert cfg.autotune_solve
     with pytest.raises(ValueError, match="backend"):
         EngineConfig(backend="vectorized")
+
+
+def test_schedule_programs_compile_ahead(monkeypatch):
+    """Every kernel program of a pipelined factorization (and of the
+    device sweeps) is compiled up front by ``compile_ahead``; the dispatch
+    loop that follows compiles nothing."""
+    from jax import monitoring
+
+    from repro.kernels import ops
+
+    log, live = [], [True]
+    monitoring.register_event_duration_secs_listener(
+        lambda ev, d, **kw: live[0] and ev.endswith("backend_compile_duration")
+        and log.append("compile"))
+    real = ops.compile_ahead
+
+    def ahead(calls):
+        real(calls)
+        log.append("ahead")
+
+    monkeypatch.setattr(ops, "compile_ahead", ahead)
+    try:
+        import jax
+
+        jax.clear_caches()                # nothing compiled yet
+        a = grid2d(7, 13, "g7x13")
+        log.clear()
+        f = multifrontal_cholesky(a, backend="pipelined")
+        assert "ahead" in log and "compile" in log[:log.index("ahead")]
+        assert "compile" not in log[log.index("ahead"):]
+        log.clear()
+        b = np.random.default_rng(0).standard_normal(a.n)
+        x = multifrontal_solve(f, b, mode="device")
+        i = log.index("ahead")
+        # after the sweeps only the final slice of x may compile
+        assert log[i + 1:].count("compile") <= 2
+        np.testing.assert_allclose(a.matvec(x), b, rtol=1e-4, atol=1e-4)
+    finally:
+        live[0] = False

@@ -111,6 +111,13 @@ def test_sharded_path_matches_host_features(mats):
     np.testing.assert_allclose(dev, host, rtol=1e-5, atol=1e-5)
 
 
+# Features of one matrix computed by differently compiled programs (eager
+# vs jit + shard_map, XLA vs the Pallas csr_stats kernels, 1 vs N shards)
+# may sum nnz_std's squared deviations in a different order: they agree to
+# this relative tolerance (a few f32 ulps), and the choices stay identical.
+FEATURE_RTOL = 1e-6
+
+
 def test_ragged_batches_all_sizes(mats):
     """Every prefix size B=1..len(mats) through the sharded path — the
     pad-to-multiple logic must be invisible at every raggedness."""
@@ -120,7 +127,8 @@ def test_ragged_batches_all_sizes(mats):
         raw = np.asarray(extract_features_batch_jnp(batch, jit=False))
         out = np.asarray(extract_features_batch_jnp(batch))
         assert out.shape == (b, len(FEATURE_NAMES))
-        assert np.array_equal(raw, out), f"mismatch at B={b}"
+        np.testing.assert_allclose(out, raw, rtol=FEATURE_RTOL, atol=0,
+                                   err_msg=f"mismatch at B={b}")
 
 
 def test_select_batch_device_path_on_mesh(mats, selector):
@@ -155,9 +163,10 @@ def test_property_sharded_featurization_identity():
 
 def test_multidevice_featurize_and_infer_identity():
     """Mesh widths 1/2/3/4 over ragged batch sizes (including B < ndev and
-    B % ndev != 0) must produce element-wise identical features and
-    identical selections."""
-    out = run_py("""
+    B % ndev != 0) must produce the same features (to FEATURE_RTOL, with
+    and without the Pallas kernels) and identical selections."""
+    out = run_py(f"""
+        RTOL = {FEATURE_RTOL!r}
         import numpy as np
         from repro.core.features import (FEATURE_NAMES,
             extract_features_batch, extract_features_batch_jnp,
@@ -189,8 +198,10 @@ def test_multidevice_featurize_and_infer_identity():
                     outp = np.asarray(extract_features_batch_jnp(
                         batch, use_pallas=True))
                     names, _ = sel.select_batch(sub, path="device")
-                assert np.array_equal(ref, out), (b, nd)
-                assert np.array_equal(ref, outp), (b, nd, "pallas")
+                np.testing.assert_allclose(out, ref, rtol=RTOL, atol=0,
+                                           err_msg=str((b, nd)))
+                np.testing.assert_allclose(outp, ref, rtol=RTOL, atol=0,
+                                           err_msg=str((b, nd, "pallas")))
                 assert names == ref_names, (b, nd)
         print("IDENTITY-OK")
     """)

@@ -223,6 +223,42 @@ def test_execute_plan_sweep_metrics(spd_grid):
     assert snap.get("solve.refine_iterations.count") == 1
     assert any(k.startswith("solve.refine_iters.") for k in snap)
     assert "stage.solve.refine.count" in snap
+    assert snap.get("solve.refine.residual.device") == 1
+    assert "solve.refine.unconverged" not in snap
+
+
+def test_residual_path_is_chosen_by_platform(spd_grid, monkeypatch):
+    """A platform without a device residual path refines with the host
+    fp64 matvec around the device sweeps — and says so."""
+    import repro.sparse.refine as refine
+    from repro.core.metrics import MetricsRegistry
+    from repro.core.plan import PlanBuilder, execute_plan
+
+    assert refine.residual_path() == "device"   # the CPU backend
+    monkeypatch.setattr(refine, "_RESIDUAL_PATHS", {})
+    assert refine.residual_path() == "host"
+    plan = PlanBuilder().build(spd_grid, algorithm="rcm")
+    b = np.random.default_rng(15).standard_normal((spd_grid.n, 2))
+    m = MetricsRegistry()
+    r = execute_plan(spd_grid, plan, b, backend="pipelined",
+                     solve_dtype="fp32_refine", sweep="device", metrics=m)
+    assert r["refine_residual"] == "host" and r["sweep"] == "device"
+    assert r["refine_converged"] and r["residual"] < 1e-10
+    assert m.snapshot().get("solve.refine.residual.host") == 1
+
+
+def test_unconverged_refinement_is_reported(spd_grid, monkeypatch):
+    import repro.sparse.refine as refine
+    from repro.core.metrics import MetricsRegistry
+    from repro.core.plan import PlanBuilder, execute_plan
+
+    monkeypatch.setattr(refine, "_should_stop", lambda *a: (True, False))
+    plan = PlanBuilder().build(spd_grid, algorithm="rcm")
+    m = MetricsRegistry()
+    r = execute_plan(spd_grid, plan, backend="pipelined",
+                     solve_dtype="fp32_refine", sweep="device", metrics=m)
+    assert r["refine_converged"] is False
+    assert m.snapshot().get("solve.refine.unconverged") == 1
 
 
 def test_engine_config_sweep_validation():
