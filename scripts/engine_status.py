@@ -173,6 +173,7 @@ def _render_mesh_panel(m: dict) -> None:
 
 
 def render_server(host: str, port: int, show_all_metrics: bool) -> int:
+    from repro.core.plan import SOLVE_STAGES
     from repro.launch.rpc import PlanRPCClient
 
     try:
@@ -209,18 +210,24 @@ def render_server(host: str, port: int, show_all_metrics: bool) -> int:
             print(f"  stage {stage:<7} p50 {s[k]:8.2f} ms   "
                   f"p99 {s[f'stage_{stage}_p99_ms']:8.2f} ms")
     # numeric solve-stage breakdown (repro.core.plan.execute_plan mirrors
-    # its RequestContext spans into stage.* histograms): host assembly vs
-    # device-blocked time vs triangular sweeps
-    solve_stages = [st for st in ("permute", "factor", "factor.assemble",
-                                  "factor.device", "solve.sweep",
-                                  "solve.refine")
-                    if f"stage.{st}.p50" in m]
+    # its RequestContext spans into stage.* histograms), children indented
+    # under their parents: host structure, assembly, device wait, sweeps
+    solve_stages = [st for st in SOLVE_STAGES if f"stage.{st}.p50" in m]
     if solve_stages:
         print("solve stages")
         for st in solve_stages:
-            print(f"  {st:<16} p50 {m[f'stage.{st}.p50'] * 1e3:8.2f} ms   "
+            name, parent = st, SOLVE_STAGES[st]
+            while parent is not None:
+                name, parent = "  " + name, SOLVE_STAGES[parent]
+            print(f"  {name:<24} p50 {m[f'stage.{st}.p50'] * 1e3:8.2f} ms   "
                   f"p99 {m[f'stage.{st}.p99'] * 1e3:8.2f} ms   "
                   f"n={int(m.get(f'stage.{st}.count', 0))}")
+        programs = m.get("compile_ahead.programs")
+        if programs is not None:
+            # a warm engine that keeps lowering programs redoes pattern
+            # work on every solve
+            print(f"  compile_ahead programs lowered {int(programs)} "
+                  f"over {int(m.get('solve.requests', 0))} solves")
         ov = m.get("solve.overlap_efficiency")
         if ov is not None:
             print(f"  overlap efficiency {ov:.2f} "

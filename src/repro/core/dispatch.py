@@ -67,7 +67,7 @@ from repro.core.metrics import MetricsRegistry
 from repro.core.plan import ExecutionPlan, PlanBuilder
 from repro.core.plan_cache import matrix_fingerprint
 from repro.core.reqctx import (DeadlineExceeded, DispatcherClosed, QueueFull,
-                               RequestContext)
+                               RequestContext, span)
 from repro.sparse.csr import CSRMatrix
 
 __all__ = ["PlanDispatcher"]
@@ -406,8 +406,10 @@ class PlanDispatcher:
             return
         t0 = time.perf_counter()
         try:
-            names = self.builder.select_names(
-                [self._inflight[key][0].mat for key in todo])
+            firsts = [self._inflight[key][0] for key in todo]
+            with span(None, "select", request_id=",".join(
+                    r.ctx.request_id for r in firsts)):
+                names = self.builder.select_names([r.mat for r in firsts])
         except Exception as exc:  # selector failure fails the whole batch
             self._c_errors.inc()
             for key in todo:
@@ -468,8 +470,9 @@ class PlanDispatcher:
             rep_ctx = self._inflight[key][0].ctx  # per-stage reorder/symbolic
             t0 = time.perf_counter()
             try:
-                plan = self.builder.build(mat, algorithm=name,
-                                          fingerprint=key, ctx=rep_ctx)
+                with span(None, "build", request_id=rep_ctx.request_id):
+                    plan = self.builder.build(mat, algorithm=name,
+                                              fingerprint=key, ctx=rep_ctx)
             except Exception as exc:
                 self._c_errors.inc()
                 with self._inflight_lock:

@@ -27,7 +27,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.plan_cache import PlanCache, matrix_fingerprint
-from repro.core.reqctx import RequestContext
+from repro.core.reqctx import RequestContext, span
 from repro.sparse.csr import CSRMatrix, permute_symmetric
 from repro.sparse.reorder import get_reordering
 from repro.sparse.symbolic import SymbolicFactor, symbolic_cholesky
@@ -83,8 +83,7 @@ class PlanBuilder:
         self.use_pallas = use_pallas
         self.batch_size = batch_size
         # optional structured-metrics mirror (repro.core.metrics registry):
-        # mesh featurize→infer work lands under `infer.*` so the serving
-        # stack's one snapshot covers the device stage too
+        # the serving mesh's per-shard utilization lands under `mesh.*`
         self.metrics = metrics
         # stage counters; builds run concurrently in the async server's
         # worker pool, so updates go through _count
@@ -111,26 +110,23 @@ class PlanBuilder:
     def build(self, a: CSRMatrix, algorithm: Optional[str] = None,
               fingerprint: Optional[str] = None,
               ctx: Optional[RequestContext] = None) -> ExecutionPlan:
-        """Build a plan from scratch (no cache involvement). A
-        :class:`RequestContext` gets per-stage spans (``select``,
-        ``reorder``, ``symbolic``) recorded into it."""
+        """Build a plan from scratch (no cache involvement). Each stage
+        (``select``, ``reorder``, ``symbolic``) is a :func:`span`, recorded
+        into a :class:`RequestContext` when one is given."""
         t_sel = 0.0
         if algorithm is None:
             if self.selector is None:
                 raise ValueError("no algorithm given and no selector set")
-            algorithm, t_sel = self.selector.select(a)
+            with span(ctx, "select"):
+                algorithm, t_sel = self.selector.select(a)
             self._count(select_calls=1, select_seconds=t_sel)
-            if ctx is not None:
-                ctx.add_span("select", t_sel)
-        t0 = time.perf_counter()  # select_seconds and build_seconds are
-        perm = get_reordering(algorithm)(a)  # disjoint stages in reports
-        t_reorder = time.perf_counter() - t0
-        pa = permute_symmetric(a, perm)
-        sym = symbolic_cholesky(pa)
+        # select_seconds and build_seconds are disjoint stages in reports
+        t0 = time.perf_counter()
+        with span(ctx, "reorder"):
+            perm = get_reordering(algorithm)(a)
+        with span(ctx, "symbolic"):
+            sym = symbolic_cholesky(permute_symmetric(a, perm))
         dt = time.perf_counter() - t0
-        if ctx is not None:
-            ctx.add_span("reorder", t_reorder)
-            ctx.add_span("symbolic", dt - t_reorder)
         self._count(sym_builds=1, plans_built=1, build_seconds=dt)
         return ExecutionPlan(
             fingerprint or matrix_fingerprint(a), algorithm,
@@ -141,12 +137,11 @@ class PlanBuilder:
                      ctx: Optional[RequestContext] = None
                      ) -> Tuple[ExecutionPlan, bool]:
         """(plan, was_hit) for one matrix through the cache."""
-        key = matrix_fingerprint(a)
+        with span(ctx, "fingerprint"):
+            key = matrix_fingerprint(a)
         if ctx is not None:
             ctx.fingerprint = key
-            with ctx.span("cache"):
-                plan = self.cache.get(key)
-        else:
+        with span(ctx, "cache"):
             plan = self.cache.get(key)
         if plan is not None:
             return plan, True
@@ -175,20 +170,15 @@ class PlanBuilder:
             got, dt = self.selector.select_batch(
                 batch, path=self.path, use_pallas=self.use_pallas)
             self._count(select_calls=1, select_seconds=dt)
-            if self.metrics is not None:
-                self.metrics.counter("infer.batches").inc()
-                self.metrics.counter("infer.matrices").inc(len(chunk))
-                self.metrics.histogram("infer.batch_s").observe(dt)
-                if self.path == "device":
-                    # per-shard utilization of the serving mesh: how many
-                    # rows of this jit bucket were live requests vs
-                    # pad-filler on each shard
-                    from repro.distributed.meshctx import (
-                        get_serving_mesh, record_shard_utilization)
+            if self.metrics is not None and self.path == "device":
+                # per-shard utilization of the serving mesh: how many rows
+                # of this jit bucket were live requests vs pad-filler on
+                # each shard
+                from repro.distributed.meshctx import (
+                    get_serving_mesh, record_shard_utilization)
 
-                    record_shard_utilization(self.metrics,
-                                             get_serving_mesh(),
-                                             len(chunk), len(batch))
+                record_shard_utilization(self.metrics, get_serving_mesh(),
+                                         len(chunk), len(batch))
             for i, name in zip(chunk, got):
                 names[i] = name
         return names  # type: ignore[return-value]
@@ -226,10 +216,25 @@ class PlanBuilder:
         return s
 
 
-#: solve-stage names as they appear in RequestContext spans and in the
-#: metrics registry (``stage.<name>`` histograms, seconds)
-SOLVE_STAGES = ("permute", "factor", "factor.assemble", "factor.device",
-                "solve", "solve.sweep", "solve.refine")
+#: the solve path's spans (RequestContext keys, trace event names and
+#: ``stage.<name>`` histograms, seconds), each with the span it nests in
+#: (None at the top level), parents first. ``factor.device`` less
+#: ``factor.drain`` is the dispatch of the bucket launches.
+SOLVE_STAGES: Dict[str, Optional[str]] = {
+    "permute": None,
+    "factor": None,
+    "factor.schedule": "factor",        # supernodes + level schedule
+    "factor.routes": "factor",          # extend-add routes and plans
+    "factor.compile_ahead": "factor",   # lower + look up every program
+    "factor.assemble": "factor",        # host scatter, drain slicing
+    "factor.device": "factor",          # per bucket: dispatch + drain
+    "factor.drain": "factor.device",    # the blocking fetch
+    "solve": None,
+    "solve.sweep": "solve",
+    "solve.sweep.setup": "solve.sweep",  # device stacks + compile_ahead
+    "solve.refine": "solve",            # fp64 residual + its sync
+    "solve.check": None,                # host fp64 residual of the answer
+}
 
 
 def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
@@ -272,12 +277,18 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
     :func:`~repro.sparse.refine.residual_path` is ``"device"``. ``b`` may be a
     single RHS ``(n,)`` or a block ``(n, k)``.
 
-    A :class:`RequestContext` gets ``permute``/``factor``/``solve`` spans
-    plus the solve-stage breakdown ``factor.assemble`` / ``factor.device``
-    / ``solve.sweep`` / ``solve.refine`` (host assembly vs device-blocked
-    vs triangular sweeps vs residual evaluation) on the level-scheduled
-    backends; a :class:`repro.core.metrics.MetricsRegistry` passed as
-    ``metrics`` mirrors every span into ``stage.<name>`` histograms and
+    Every stage is a :func:`repro.core.reqctx.span` opened where its work
+    runs, here and in the backends, which receive the context: the
+    :data:`SOLVE_STAGES` (``factor.schedule`` / ``factor.routes`` /
+    ``factor.compile_ahead`` / ``factor.assemble`` / ``factor.device`` >
+    ``factor.drain`` on the level-scheduled backends, ``solve.sweep`` >
+    ``solve.sweep.setup`` / ``solve.refine`` in the solve) land in
+    ``ctx.spans`` and, as annotations, in a device trace. Without a
+    ``ctx`` the spans go to a private context. A
+    :class:`repro.core.metrics.MetricsRegistry` passed as ``metrics``
+    mirrors this call's spans into ``stage.<name>`` histograms and its
+    counts into counters (``compile_ahead.programs``: the programs
+    ``compile_ahead`` lowered), and
     records the backend's ``solve.overlap_efficiency`` gauge, the sweep
     substrate (``solve.sweep.<mode>`` counters) and the refinement
     behavior (``solve.refine_iterations`` histogram plus per-count
@@ -296,16 +307,16 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
     if b is None:
         b = np.random.default_rng(0).standard_normal(a.n)
     perm = plan.perm
-    t0 = time.perf_counter()
-    pa = permute_symmetric(a, perm)
-    t_perm = time.perf_counter() - t0
+    own = ctx if isinstance(ctx, RequestContext) else RequestContext.mint()
+    spans0, counts0 = dict(own.spans), dict(own.counts)
+    with span(own, "permute"):
+        pa = permute_symmetric(a, perm)
 
     refine_info = None
     refine_residual = None
     eff_dtype = solve_dtype
     eff_sweep = sweep
     fstats: dict = {}
-    t0 = time.perf_counter()
     if solver == "multifrontal":
         from repro.sparse.multifrontal import (multifrontal_cholesky,
                                                multifrontal_solve)
@@ -313,71 +324,69 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
                 or sweep == "device") and solve_dtype == "fp64":
             eff_dtype = "fp32_refine"  # f32 factor and/or f32 sweeps
         dtype = np.float64 if eff_dtype == "fp64" else np.float32
-        # ctx rides into the numeric phase: the level-scheduled backends
-        # re-check the deadline at level boundaries and abandon the
-        # factorization mid-flight with DeadlineExceeded
-        f = multifrontal_cholesky(pa, sym=plan.sym, backend=backend,
-                                  dtype=dtype, pad=pad, bs=bs, ctx=ctx)
+        # ctx rides into the numeric phase: the backends open their spans
+        # on it, and the level-scheduled ones re-check the deadline at
+        # level boundaries and abandon the factorization mid-flight with
+        # DeadlineExceeded
+        with span(own, "factor"):
+            f = multifrontal_cholesky(pa, sym=plan.sym, backend=backend,
+                                      dtype=dtype, pad=pad, bs=bs,
+                                      ctx=own if ctx is None else ctx)
         fstats = f.stats
-        t_fac = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        if eff_sweep == "auto":
-            eff_sweep = "seq" if f.schedule is None else "level"
-        # hoisted: one permute + fp64 cast of the RHS, outside any
-        # refinement loop (the closures below only ever see residuals)
-        pb = np.ascontiguousarray(b[perm], dtype=np.float64)
-        if eff_dtype == "fp32_refine":
-            from repro.sparse.refine import residual_path
-            # device sweeps keep the residual on the device where the
-            # platform can run the f64 matvec; host sweeps always pair
-            # with the host fp64 matvec
-            refine_residual = (residual_path() if eff_sweep == "device"
-                               else "host")
-        if refine_residual == "device":
-            from repro.sparse.refine import refine_solve_device
-            z, refine_info = refine_solve_device(pa, f, pb,
-                                                 sweep_bs=sweep_bs, rt=rt)
-        elif eff_dtype == "fp32_refine":
-            from repro.sparse.refine import refine_solve
-            z, refine_info = refine_solve(
-                pa.matvec,
-                lambda r: multifrontal_solve(f, r, mode=eff_sweep,
-                                             sweep_bs=sweep_bs, rt=rt),
-                pb)
-        else:
-            z = multifrontal_solve(f, pb, mode=eff_sweep,
-                                   sweep_bs=sweep_bs, rt=rt)
+        with span(own, "solve"):
+            if eff_sweep == "auto":
+                eff_sweep = "seq" if f.schedule is None else "level"
+            # hoisted: one permute + fp64 cast of the RHS, outside any
+            # refinement loop (the closures below only ever see residuals)
+            pb = np.ascontiguousarray(b[perm], dtype=np.float64)
+            if eff_dtype == "fp32_refine":
+                from repro.sparse.refine import residual_path
+                # device sweeps keep the residual on the device where the
+                # platform can run the f64 matvec; host sweeps always pair
+                # with the host fp64 matvec
+                refine_residual = (residual_path() if eff_sweep == "device"
+                                   else "host")
+            if refine_residual == "device":
+                from repro.sparse.refine import refine_solve_device
+                z, refine_info = refine_solve_device(
+                    pa, f, pb, sweep_bs=sweep_bs, rt=rt, ctx=own)
+            elif eff_dtype == "fp32_refine":
+                from repro.sparse.refine import refine_solve
+                z, refine_info = refine_solve(
+                    pa.matvec,
+                    lambda r: multifrontal_solve(f, r, mode=eff_sweep,
+                                                 sweep_bs=sweep_bs, rt=rt,
+                                                 ctx=own),
+                    pb, ctx=own)
+            else:
+                with span(own, "solve.sweep"):
+                    z = multifrontal_solve(f, pb, mode=eff_sweep,
+                                           sweep_bs=sweep_bs, rt=rt,
+                                           ctx=own)
     elif solver == "simplicial":
         from repro.sparse.numeric import cholesky_solve, sparse_cholesky
         eff_dtype = "fp64"  # simplicial path is host fp64 only
         eff_sweep = "seq"
-        f = sparse_cholesky(pa, sym=plan.sym)
-        t_fac = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        z = cholesky_solve(f, b[perm])
+        with span(own, "factor"):
+            f = sparse_cholesky(pa, sym=plan.sym)
+        with span(own, "solve"), span(own, "solve.sweep"):
+            z = cholesky_solve(f, b[perm])
     else:
         raise ValueError(f"unknown solver {solver!r}")
-    t_sol = time.perf_counter() - t0
+    x = np.empty_like(z)
+    x[perm] = z
+    with span(own, "solve.check"):
+        resid = float(np.linalg.norm(a.matvec(x) - b)
+                      / max(np.linalg.norm(b), 1e-30))
 
-    # solve-stage breakdown: host assembly vs device-blocked time comes
-    # from the backend's own timers; on the refined paths the solve splits
-    # into triangular sweeps vs residual evaluation (RefineInfo timers),
-    # otherwise the sweeps are the whole of t_sol
-    spans = {"permute": t_perm, "factor": t_fac, "solve": t_sol,
-             "solve.sweep": t_sol}
-    if refine_info is not None:
-        spans["solve.sweep"] = refine_info.t_sweep
-        spans["solve.refine"] = refine_info.t_residual
-    if "t_factor_assemble" in fstats:
-        spans["factor.assemble"] = fstats["t_factor_assemble"]
-        spans["factor.device"] = (fstats.get("t_factor_dispatch", 0.0)
-                                  + fstats.get("t_factor_sync", 0.0))
-    if ctx is not None:
-        for stage, dt in spans.items():
-            ctx.add_span(stage, dt)
+    spans = own.spans_since(spans0)
     if metrics is not None:
+        # the one place spans and counts reach the registry
         for stage, dt in spans.items():
             metrics.histogram(f"stage.{stage}").observe(dt)
+        for name, n in own.counts.items():
+            if n != counts0.get(name, 0):
+                metrics.counter(name).inc(n - counts0.get(name, 0))
         if "overlap_efficiency" in fstats:
             metrics.gauge("solve.overlap_efficiency").set(
                 fstats["overlap_efficiency"])
@@ -391,10 +400,8 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
             metrics.counter(f"solve.refine.residual.{refine_residual}").inc()
             if not refine_info.converged:
                 metrics.counter("solve.refine.unconverged").inc()
-    x = np.empty_like(z)
-    x[perm] = z
-    resid = float(np.linalg.norm(a.matvec(x) - b)
-                  / max(np.linalg.norm(b), 1e-30))
+    t_perm, t_fac, t_sol = (spans.get(k, 0.0)
+                            for k in ("permute", "factor", "solve"))
     plan.meta["solve_backend"] = backend
     plan.meta["solve_dtype"] = eff_dtype
     plan.meta["solve_bs"] = bs

@@ -18,6 +18,13 @@ accumulating **span timings** (stage name → seconds) along the way, so a
 dispatcher can *shed* a request whose deadline has already passed instead
 of spending a build worker on an answer nobody is waiting for.
 
+:func:`span` is the one timing primitive of the serving and solve paths:
+it opens a ``jax.profiler.TraceAnnotation`` of the stage's name around the
+work where it runs (so a device trace shows the host stage on its own
+clock) and adds the wall time to the context's ``spans`` on exit. Code that
+may run without a context calls it with ``ctx=None`` and gets the
+annotation alone. With no profiler running an annotation costs ~1 µs.
+
 The typed serving errors live here too — they are the vocabulary every
 layer (and the RPC client, which re-raises them by name) shares:
 
@@ -36,13 +43,14 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import itertools
 import threading
 import time
 import uuid
 from typing import Dict, Optional
 
-__all__ = ["RequestContext", "ServingError", "DeadlineExceeded",
+__all__ = ["RequestContext", "span", "ServingError", "DeadlineExceeded",
            "QueueFull", "DispatcherClosed", "SERVING_ERRORS"]
 
 
@@ -75,17 +83,64 @@ SERVING_ERRORS: Dict[str, type] = {
 _ID_PREFIX = uuid.uuid4().hex[:8]
 _ID_SEQ = itertools.count()
 
+# nesting depth of open spans on each thread: the outermost span of a
+# thread carries the request id, the ones inside it take it from nesting
+_DEPTH = threading.local()
+
+
+@functools.cache
+def _annotation():
+    # imported on first use: the RPC client imports this module for the
+    # error types and never opens a span
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
+@contextlib.contextmanager
+def span(ctx: Optional["RequestContext"], name: str, **stats):
+    """``with span(ctx, "factor.routes"): ...`` — one stage of a request.
+
+    Opens ``jax.profiler.TraceAnnotation(name, **stats)`` (keyword
+    arguments become the trace event's stats, the event keeps the bare
+    name) and, when ``ctx`` is given, adds the wall time to
+    ``ctx.spans[name]`` on exit, also when the body raises. The outermost
+    span on a thread gets ``request_id=ctx.request_id`` as a stat. Spans
+    nest: a parent's self time is its duration less its children's.
+    Yields the annotation, whose ``set_metadata(**stats)`` adds stats
+    known only inside the body.
+    """
+    depth = getattr(_DEPTH, "n", 0)
+    if ctx is not None and depth == 0:
+        stats.setdefault("request_id", ctx.request_id)
+    _DEPTH.n = depth + 1
+    t0 = time.perf_counter()
+    try:
+        with _annotation()(name, **stats) as ann:
+            yield ann
+    finally:
+        if ctx is not None:
+            ctx.add_span(name, time.perf_counter() - t0)
+        _DEPTH.n = depth
+
 
 @dataclasses.dataclass
 class RequestContext:
     """Identity + budget + telemetry for one serving request.
 
-    ``spans`` maps a stage name (``queue``, ``select``, ``reorder``,
-    ``symbolic``, ``build``, ``cache``, ``permute``, ``factor``, ``solve``,
-    ``total``) to accumulated seconds; re-entering a stage adds to it.
-    ``deadline_s`` is an absolute :func:`time.perf_counter` instant or
-    ``None`` (no deadline). ``priority`` — higher is served first; ties
-    are FIFO.
+    ``spans`` maps a stage name to accumulated seconds; re-entering a
+    stage adds to it. Plan path: ``queue``, ``select`` (with
+    ``select.pack`` in the trace only), ``build`` > ``reorder`` /
+    ``symbolic``, ``fingerprint``, ``cache``, ``total``. Solve path
+    (:data:`repro.core.plan.SOLVE_STAGES`, parent > children):
+    ``permute``; ``factor`` > ``factor.schedule`` / ``factor.routes`` /
+    ``factor.compile_ahead`` / ``factor.assemble`` / ``factor.device`` >
+    ``factor.drain``; ``solve`` > ``solve.sweep`` > ``solve.sweep.setup``,
+    ``solve`` > ``solve.refine``; ``solve.check``. ``counts`` maps a
+    counter name (``compile_ahead.programs``) to a count the request
+    caused. ``deadline_s`` is an absolute :func:`time.perf_counter`
+    instant or ``None`` (no deadline). ``priority`` — higher is served
+    first; ties are FIFO.
     """
 
     request_id: str
@@ -94,6 +149,7 @@ class RequestContext:
     t_arrival: float = dataclasses.field(default_factory=time.perf_counter)
     deadline_s: Optional[float] = None
     spans: Dict[str, float] = dataclasses.field(default_factory=dict)
+    counts: Dict[str, int] = dataclasses.field(default_factory=dict)
     meta: Dict[str, object] = dataclasses.field(default_factory=dict)
 
     # spans may be written from the batcher thread while (e.g.) an RPC
@@ -135,15 +191,22 @@ class RequestContext:
         with self._lock:
             self.spans[stage] = self.spans.get(stage, 0.0) + float(seconds)
 
-    @contextlib.contextmanager
-    def span(self, stage: str):
-        """``with ctx.span("symbolic"): ...`` — accumulate wall time, even
+    def span(self, stage: str, **stats):
+        """``with ctx.span("symbolic"): ...`` — :func:`span` on this
+        context: a trace annotation, and the wall time accumulated even
         when the body raises (the time was still spent on this request)."""
-        t0 = time.perf_counter()
-        try:
-            yield self
-        finally:
-            self.add_span(stage, time.perf_counter() - t0)
+        return span(self, stage, **stats)
+
+    def add_count(self, name: str, n: int) -> None:
+        with self._lock:
+            self.counts[name] = self.counts.get(name, 0) + int(n)
+
+    def spans_since(self, before: Dict[str, float]) -> Dict[str, float]:
+        """Seconds each stage gained since the ``dict(ctx.spans)`` snapshot
+        ``before``; stages that gained nothing are left out."""
+        with self._lock:
+            return {k: v - before.get(k, 0.0) for k, v in self.spans.items()
+                    if k not in before or v != before[k]}
 
     def spans_ms(self) -> Dict[str, float]:
         """Wire-friendly copy: stage → milliseconds."""

@@ -25,6 +25,7 @@ from repro.core.features import pad_csr_batch
 from repro.core.labeling import LabeledDataset
 from repro.core.ml import MODEL_ZOO, BaseClassifier, accuracy_score
 from repro.core.model_selection import GridSearchCV, train_test_split
+from repro.core.reqctx import span
 from repro.core.scaling import SCALERS
 from repro.engine.registry import FeatureSet, get_feature_set
 from repro.sparse.csr import CSRMatrix
@@ -117,8 +118,9 @@ class ReorderSelector:
         fs = self._fs()
         if path == "device" and fs.extract_batch_jnp is not None:
             # device featurizers consume the padded-CSR wire format
-            feats = fs.extract_batch_jnp(
-                pad_csr_batch(mats, bucket=True), use_pallas=use_pallas)
+            with span(None, "select.pack"):
+                packed = pad_csr_batch(mats, bucket=True)
+            feats = fs.extract_batch_jnp(packed, use_pallas=use_pallas)
             idx = self._predict_device(feats)
         else:  # host path, or a feature set with no device extractor
             idx = self.predict_features(fs.batch(mats))

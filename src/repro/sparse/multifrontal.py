@@ -28,8 +28,15 @@ factorizations of fronts — matmul-shaped work for the MXU. Four backends:
                 previous level factors — the only host↔device sync is one
                 drain at the end. ``stats`` records where the wall time
                 went (``t_factor_assemble`` / ``t_factor_dispatch`` /
-                ``t_factor_sync``) and the resulting ``overlap_efficiency``
-                (host-busy fraction of the overlappable time).
+                ``t_factor_sync``, read from the factorization's spans) and
+                the resulting ``overlap_efficiency`` (host-busy fraction of
+                the overlappable time).
+
+Each stage of a factorization and of the device sweeps is a
+:func:`repro.core.reqctx.span` on the caller's request context (or a
+private one): ``factor.schedule``, ``factor.routes``,
+``factor.compile_ahead``, per level-bucket ``factor.assemble`` and
+``factor.device`` > ``factor.drain``, and ``solve.sweep.setup``.
 
 The triangular solves are level-batched too: :func:`multifrontal_solve`
 stacks each level's factors into (B, P, P)/(B, R, P) tensors once and runs
@@ -209,27 +216,41 @@ def multifrontal_cholesky(
     :func:`multifrontal_solve` can run level-batched sweeps.
 
     ``ctx`` is an optional :class:`repro.core.reqctx.RequestContext`: the
+    factorization's stage spans are recorded into it (into a private one
+    when it is None or only a deadline; the level-scheduled backends'
+    ``t_factor_*`` stats are read from them either way), and the
     level-scheduled backends re-check its deadline at every assembly-tree
     level boundary and abandon the factorization with
     :class:`~repro.core.reqctx.DeadlineExceeded` once it has passed —
     serving-path deadline discipline extends into the numeric solve
     instead of stopping at plan build.
     """
+    from repro.core.reqctx import RequestContext, span
+
     assert a.data is not None, "numeric factorization needs values"
+    rec = ctx if isinstance(ctx, RequestContext) else RequestContext.mint()
+    spans0 = dict(rec.spans)
     if sym is None:
         sym = symbolic_cholesky(a)
-    snode_ptr, snode_of = supernodes(sym, relax=relax)
-    schedule = build_schedule(sym, snode_ptr, snode_of, pad=pad)
+    with span(rec, "factor.schedule"):
+        snode_ptr, snode_of = supernodes(sym, relax=relax)
+        schedule = build_schedule(sym, snode_ptr, snode_of, pad=pad)
     eff_dtype = np.dtype(np.float32 if backend in DEVICE_BACKENDS else dtype)
 
     timings: dict = {}
     device_stacks = None
     _check_deadline(ctx, "factorization start")
-    if backend == "batched":
-        fronts, timings = _factor_batched(a, schedule, bs=bs, ctx=ctx)
-    elif backend == "pipelined":
-        fronts, timings, device_stacks = _factor_pipelined(a, schedule,
-                                                           bs=bs, ctx=ctx)
+    if backend in ("batched", "pipelined"):
+        if backend == "batched":
+            fronts = _factor_batched(a, schedule, rec, bs=bs, ctx=ctx)
+        else:
+            fronts, device_stacks = _factor_pipelined(a, schedule, rec,
+                                                      bs=bs, ctx=ctx)
+        d = rec.spans_since(spans0)
+        t_drain = d.get("factor.drain", 0.0)
+        timings = _overlap_timings(d.get("factor.assemble", 0.0),
+                                   d.get("factor.device", 0.0) - t_drain,
+                                   t_drain)
     else:
         fronts = _factor_sequential(a, schedule, backend, eff_dtype)
 
@@ -300,49 +321,46 @@ def _assemble_bucket(a: CSRMatrix, schedule: LevelSchedule,
     return W
 
 
-def _factor_batched(a: CSRMatrix, schedule: LevelSchedule,
-                    bs: Optional[int] = None, ctx=None
-                    ) -> Tuple[List[_Front], dict]:
+def _factor_batched(a: CSRMatrix, schedule: LevelSchedule, rec,
+                    bs: Optional[int] = None, ctx=None) -> List[_Front]:
     """Level-scheduled factorization: per (level, bucket), assemble every
     member front into one padded f32 workspace stack and factor the stack
     in a single batched kernel launch. Extend-add runs on the host (numpy
     scatter into the next level's workspaces) and every kernel call is a
-    blocking round trip — the ``pipelined`` backend removes both."""
+    blocking round trip — the ``pipelined`` backend removes both — so
+    each bucket's ``factor.device`` span is all ``factor.drain``. Spans go
+    to ``rec``; ``ctx`` holds the deadline."""
+    from repro.core.reqctx import span
     from repro.kernels import ops
 
-    pc = time.perf_counter
     nsup = schedule.nsup
     fronts: List[Optional[_Front]] = [None] * nsup
     pending: List[List[Tuple[np.ndarray, np.ndarray]]] = [[] for _ in range(nsup)]
-    t_asm = t_sync = 0.0
     for li in range(schedule.nlevels):
         _check_deadline(ctx, f"batched level {li}/{schedule.nlevels}")
         for bucket in schedule.buckets[li]:
-            t0 = pc()
             P = bucket.P
-            W = _assemble_bucket(a, schedule, bucket)
-            for bi, k in enumerate(bucket.members):
-                fp = schedule.fronts[k]
-                shift = P - fp.npiv
-                for (urows, U) in pending[k]:
-                    _extend_add(W[bi], fp, urows, U, shift)
-                pending[k] = []
-            t_asm += pc() - t0
-            t0 = pc()
-            Wf = np.asarray(ops.frontal_factor_batch_ws(W, P, bs=bs))
-            t_sync += pc() - t0
-            t0 = pc()
-            for bi, k in enumerate(bucket.members):
-                fp = schedule.fronts[k]
-                npiv, nrest = fp.npiv, fp.nrest
-                L11 = np.tril(Wf[bi, :npiv, :npiv])
-                L21 = Wf[bi, P : P + nrest, :npiv]
-                fronts[k] = _Front((fp.c0, fp.c1), fp.rows, L11, L21)
-                if nrest:
-                    S = Wf[bi, P : P + nrest, P : P + nrest]
-                    pending[fp.parent].append((fp.rows[npiv:], S))
-            t_asm += pc() - t0
-    return fronts, _overlap_timings(t_asm, 0.0, t_sync)  # type: ignore[return-value]
+            with span(rec, "factor.assemble"):
+                W = _assemble_bucket(a, schedule, bucket)
+                for bi, k in enumerate(bucket.members):
+                    fp = schedule.fronts[k]
+                    shift = P - fp.npiv
+                    for (urows, U) in pending[k]:
+                        _extend_add(W[bi], fp, urows, U, shift)
+                    pending[k] = []
+            with span(rec, "factor.device"), span(rec, "factor.drain"):
+                Wf = np.asarray(ops.frontal_factor_batch_ws(W, P, bs=bs))
+            with span(rec, "factor.assemble"):
+                for bi, k in enumerate(bucket.members):
+                    fp = schedule.fronts[k]
+                    npiv, nrest = fp.npiv, fp.nrest
+                    L11 = np.tril(Wf[bi, :npiv, :npiv])
+                    L21 = Wf[bi, P : P + nrest, :npiv]
+                    fronts[k] = _Front((fp.c0, fp.c1), fp.rows, L11, L21)
+                    if nrest:
+                        S = Wf[bi, P : P + nrest, P : P + nrest]
+                        pending[fp.parent].append((fp.rows[npiv:], S))
+    return fronts  # type: ignore[return-value]
 
 
 def _route_contributions(schedule: LevelSchedule) -> dict:
@@ -455,9 +473,9 @@ def _pipelined_calls(ops, schedule: LevelSchedule, ea_plans: dict,
     return calls
 
 
-def _factor_pipelined(a: CSRMatrix, schedule: LevelSchedule,
+def _factor_pipelined(a: CSRMatrix, schedule: LevelSchedule, rec,
                       bs: Optional[int] = None, ctx=None
-                      ) -> Tuple[List[_Front], dict, dict]:
+                      ) -> Tuple[List[_Front], dict]:
     """Pipelined device-resident factorization.
 
     Producer/consumer split: the host's only numeric work is scattering A's
@@ -471,62 +489,63 @@ def _factor_pipelined(a: CSRMatrix, schedule: LevelSchedule,
     :func:`repro.kernels.ops.extend_add_batch`); the single blocking sync
     is the drain at the end that fetches the factored stacks for the
     host-side triangular sweeps. The factored device stacks are *also*
-    returned (third element) and retained on the factor: ``sweep="device"``
+    returned (second element) and retained on the factor: ``sweep="device"``
     slices L11/L21 straight out of them, so device sweeps never re-upload
     the factors the drain just pulled down. Before the first dispatch,
     every kernel program of the schedule is compiled concurrently
-    (:func:`repro.kernels.ops.compile_ahead`; ``t_factor_compile``).
+    (:func:`repro.kernels.ops.compile_ahead`), under the span
+    ``factor.compile_ahead`` with the number of programs as its
+    ``programs`` stat and in ``rec.counts["compile_ahead.programs"]``.
+    Spans go to ``rec``; ``ctx`` holds the deadline.
     """
     import jax.numpy as jnp
 
+    from repro.core.reqctx import span
     from repro.kernels import ops
 
-    pc = time.perf_counter
     nsup = schedule.nsup
     fronts: List[Optional[_Front]] = [None] * nsup
-    ea_plans = {key: _extend_add_plan(schedule, sorted(srcs.items()))
-                for key, srcs in _route_contributions(schedule).items()}
-    t0 = pc()
-    ops.compile_ahead(_pipelined_calls(ops, schedule, ea_plans, bs))
-    t_compile = pc() - t0
+    with span(rec, "factor.routes"):
+        ea_plans = {key: _extend_add_plan(schedule, sorted(srcs.items()))
+                    for key, srcs in _route_contributions(schedule).items()}
+    with span(rec, "factor.compile_ahead") as sp:
+        calls = _pipelined_calls(ops, schedule, ea_plans, bs)
+        ops.compile_ahead(calls)
+        sp.set_metadata(programs=len(calls))
+    rec.add_count("compile_ahead.programs", len(calls))
     dev: dict = {}             # (level, bucket) -> factored device stack
-    t_asm = t_disp = t_sync = 0.0
     for li in range(schedule.nlevels):
         _check_deadline(ctx, f"pipelined dispatch level "
                              f"{li}/{schedule.nlevels}")
         for bj, bucket in enumerate(schedule.buckets[li]):
-            t0 = pc()
-            W = _assemble_bucket(a, schedule, bucket)
-            t_asm += pc() - t0
-            t0 = pc()
-            w = jnp.asarray(W)
-            ea = ea_plans.get((li, bj))
-            if ea is not None:
-                w = ops.extend_add_stacks(
-                    w, [dev[k] for k in ea.sources], ea.src_ids, ea.order,
-                    ea.dst, ea.rows, offsets=ea.offsets, rmax=ea.rmax)
-            dev[(li, bj)] = ops.frontal_factor_batch_ws(w, bucket.P, bs=bs)
-            t_disp += pc() - t0
+            with span(rec, "factor.assemble"):
+                W = _assemble_bucket(a, schedule, bucket)
+            with span(rec, "factor.device"):
+                w = jnp.asarray(W)
+                ea = ea_plans.get((li, bj))
+                if ea is not None:
+                    w = ops.extend_add_stacks(
+                        w, [dev[k] for k in ea.sources], ea.src_ids,
+                        ea.order, ea.dst, ea.rows, offsets=ea.offsets,
+                        rmax=ea.rmax)
+                dev[(li, bj)] = ops.frontal_factor_batch_ws(w, bucket.P,
+                                                            bs=bs)
     # drain: the only host↔device sync — by now the host has assembled and
     # dispatched every level, so this wait is whatever device work is left
     for li in range(schedule.nlevels):
         _check_deadline(ctx, f"pipelined drain level "
                              f"{li}/{schedule.nlevels}")
         for bj, bucket in enumerate(schedule.buckets[li]):
-            t0 = pc()
-            Wf = np.asarray(dev[(li, bj)])
-            t_sync += pc() - t0
-            t0 = pc()
-            P = bucket.P
-            for bi, k in enumerate(bucket.members):
-                fp = schedule.fronts[k]
-                L11 = np.tril(Wf[bi, : fp.npiv, : fp.npiv])
-                L21 = Wf[bi, P : P + fp.nrest, : fp.npiv]
-                fronts[k] = _Front((fp.c0, fp.c1), fp.rows, L11, L21)
-            t_asm += pc() - t0
-    timings = _overlap_timings(t_asm, t_disp, t_sync)
-    timings["t_factor_compile"] = t_compile
-    return fronts, timings, dev  # type: ignore[return-value]
+            with span(rec, "factor.device"), span(rec, "factor.drain"):
+                Wf = np.asarray(dev[(li, bj)])
+            with span(rec, "factor.assemble"):
+                P = bucket.P
+                for bi, k in enumerate(bucket.members):
+                    fp = schedule.fronts[k]
+                    L11 = np.tril(Wf[bi, : fp.npiv, : fp.npiv])
+                    L21 = Wf[bi, P : P + fp.nrest, : fp.npiv]
+                    fronts[k] = _Front((fp.c0, fp.c1), fp.rows, L11, L21)
+    return fronts, dev  # type: ignore[return-value]
 
 
 # ---------------------------------------------------------------------------
@@ -727,21 +746,30 @@ def _build_device_sweeps(f: MultifrontalFactor) -> _DeviceSweeps:
 
 def _device_sweep_passes(f: MultifrontalFactor, x, *,
                          sweep_bs: Optional[int] = None,
-                         rt: Optional[int] = None):
+                         rt: Optional[int] = None, ctx=None):
     """Forward + backward substitution on a device-resident (n + 1, K) f32
     RHS block. One asynchronously dispatched jit step per level-bucket; no
-    host sync anywhere — callers decide when to pull the result."""
+    host sync anywhere — callers decide when to pull the result. The first
+    pass at a RHS width stacks the factors and compiles the sweep programs
+    under the span ``solve.sweep.setup`` (stat ``programs``; the count
+    also goes to ``ctx.counts["compile_ahead.programs"]``)."""
+    from repro.core.reqctx import span
     from repro.kernels import ops
 
-    if f._dev_sweeps is None:
-        f._dev_sweeps = _build_device_sweeps(f)
-    sw = f._dev_sweeps
     key = (x.shape[1], sweep_bs, rt)
-    if key not in sw.compiled:
-        ops.compile_ahead(c for groups in sw.levels for g in groups
-                          for c in ops.sweep_calls(x, g.W, g.piv, g.rest,
-                                                   bs=sweep_bs, rt=rt))
-        sw.compiled.add(key)
+    if f._dev_sweeps is None or key not in f._dev_sweeps.compiled:
+        with span(ctx, "solve.sweep.setup") as sp:
+            if f._dev_sweeps is None:
+                f._dev_sweeps = _build_device_sweeps(f)
+            calls = [c for groups in f._dev_sweeps.levels for g in groups
+                     for c in ops.sweep_calls(x, g.W, g.piv, g.rest,
+                                              bs=sweep_bs, rt=rt)]
+            ops.compile_ahead(calls)
+            f._dev_sweeps.compiled.add(key)
+            sp.set_metadata(programs=len(calls))
+        if ctx is not None:
+            ctx.add_count("compile_ahead.programs", len(calls))
+    sw = f._dev_sweeps
     for groups in sw.levels:
         for g in groups:
             x = ops.sweep_forward(x, g.W, g.piv, g.rest, bs=sweep_bs,
@@ -755,7 +783,7 @@ def _device_sweep_passes(f: MultifrontalFactor, x, *,
 
 def _solve_device(f: MultifrontalFactor, b2: np.ndarray, *,
                   sweep_bs: Optional[int] = None,
-                  rt: Optional[int] = None) -> np.ndarray:
+                  rt: Optional[int] = None, ctx=None) -> np.ndarray:
     """Device-resident sweeps for an (n, k) RHS block: upload once, one
     async dispatch per level-bucket, one sync to fetch the solution."""
     import jax.numpy as jnp
@@ -765,7 +793,8 @@ def _solve_device(f: MultifrontalFactor, b2: np.ndarray, *,
     n, k = b2.shape
     xb = np.zeros((n + 1, rhs_width(k)), dtype=np.float32)
     xb[:n, :k] = b2
-    x = _device_sweep_passes(f, jnp.asarray(xb), sweep_bs=sweep_bs, rt=rt)
+    x = _device_sweep_passes(f, jnp.asarray(xb), sweep_bs=sweep_bs, rt=rt,
+                             ctx=ctx)
     return np.asarray(x[:n, :k], dtype=np.float64)
 
 
@@ -775,7 +804,7 @@ SweepMode = Literal["auto", "level", "seq", "device"]
 def multifrontal_solve(f: MultifrontalFactor, b: np.ndarray,
                        mode: SweepMode = "auto", *,
                        sweep_bs: Optional[int] = None,
-                       rt: Optional[int] = None) -> np.ndarray:
+                       rt: Optional[int] = None, ctx=None) -> np.ndarray:
     """Solve A x = b with the supernodal factor.
 
     ``b`` may be a single RHS ``(n,)`` or a block ``(n, k)`` — all sweep
@@ -787,7 +816,9 @@ def multifrontal_solve(f: MultifrontalFactor, b: np.ndarray,
     with refinement for fp64 residuals). ``sweep_bs``/``rt`` are the
     autotuned device-sweep knobs (tri-solve panel cap and RHS tile width);
     both are ignored by the host modes. Repeated solves reuse the stacked
-    sweep tensors cached on the factor.
+    sweep tensors cached on the factor. ``ctx`` (a
+    :class:`repro.core.reqctx.RequestContext` or None) receives the device
+    mode's ``solve.sweep.setup`` span.
     """
     b = np.asarray(b)
     single = b.ndim == 1
@@ -797,7 +828,7 @@ def multifrontal_solve(f: MultifrontalFactor, b: np.ndarray,
         raise ValueError(f"mode={mode!r} needs a factor with a schedule")
     if mode == "device":
         x = _solve_device(f, b[:, None] if single else b,
-                          sweep_bs=sweep_bs, rt=rt)
+                          sweep_bs=sweep_bs, rt=rt, ctx=ctx)
         return x[:, 0] if single else x
     x = np.array(b, dtype=np.float64)   # the one owned fp64 copy
     x2 = x[:, None] if single else x    # view — sweeps mutate in place

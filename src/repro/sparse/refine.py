@@ -35,7 +35,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Callable, List, Optional, Tuple
 
 import numpy as np
@@ -52,9 +51,10 @@ class RefineInfo:
     iterations: int          # correction sweeps applied (0 = first solve enough)
     residuals: List[float]   # relative residual after each evaluation
     converged: bool
-    # where the solve-phase wall time went: triangular sweeps vs residual
-    # evaluation (on the device loop the residual timer includes the one
-    # scalar sync per iteration, where queued sweep work completes)
+    # where the solve-phase wall time went, read from the loop's
+    # ``solve.sweep`` and ``solve.refine`` spans: triangular sweeps vs
+    # residual evaluation (on the device loop the residual span includes
+    # the one scalar sync per iteration, where queued sweep work completes)
     t_sweep: float = 0.0
     t_residual: float = 0.0
 
@@ -82,39 +82,48 @@ def refine_solve(matvec: Callable[[np.ndarray], np.ndarray],
                  solve: Callable[[np.ndarray], np.ndarray],
                  b: np.ndarray, *,
                  tol: float = DEFAULT_TOL,
-                 max_iter: int = 10) -> tuple[np.ndarray, RefineInfo]:
+                 max_iter: int = 10,
+                 ctx=None) -> tuple[np.ndarray, RefineInfo]:
     """Solve A x = b to fp64 accuracy using a low-precision inner solver.
 
     ``matvec`` must be the fp64 operator of A; ``solve`` is the (possibly
     low-precision) factorization solve applied to an fp64 right-hand side.
     ``b`` may be ``(n,)`` or an ``(n, k)`` RHS block (both closures must
     then accept blocks; the residual norm is Frobenius over the block).
+    Each solve is a ``solve.sweep`` span and each residual a
+    ``solve.refine`` span on ``ctx`` (a private context when None).
     Returns ``(x, RefineInfo)``.
     """
-    pc = time.perf_counter
+    from repro.core.reqctx import RequestContext, span
+
     b = np.asarray(b, dtype=np.float64)
     nb = float(np.linalg.norm(b))
     if nb == 0.0:
         return np.zeros_like(b), RefineInfo(0, [0.0], True)
-    t0 = pc()
-    x = np.asarray(solve(b), dtype=np.float64)
-    t_sweep = pc() - t0
+    own = ctx if ctx is not None else RequestContext.mint()
+    spans0 = dict(own.spans)
+    with span(own, "solve.sweep"):
+        x = np.asarray(solve(b), dtype=np.float64)
     residuals: List[float] = []
     iters = 0
-    t_res = 0.0
     while True:
-        t0 = pc()
-        r = b - np.asarray(matvec(x), dtype=np.float64)
-        rel = float(np.linalg.norm(r)) / nb
-        t_res += pc() - t0
+        with span(own, "solve.refine"):
+            r = b - np.asarray(matvec(x), dtype=np.float64)
+            rel = float(np.linalg.norm(r)) / nb
         residuals.append(rel)
         stop, ok = _should_stop(residuals, tol, iters, max_iter)
         if stop:
-            return x, RefineInfo(iters, residuals, ok, t_sweep, t_res)
-        t0 = pc()
-        x = x + np.asarray(solve(r), dtype=np.float64)
-        t_sweep += pc() - t0
+            return x, _info(iters, residuals, ok, own, spans0)
+        with span(own, "solve.sweep"):
+            x = x + np.asarray(solve(r), dtype=np.float64)
         iters += 1
+
+
+def _info(iters: int, residuals: List[float], ok: bool, ctx,
+          spans0: dict) -> RefineInfo:
+    d = ctx.spans_since(spans0)
+    return RefineInfo(iters, residuals, ok, d.get("solve.sweep", 0.0),
+                      d.get("solve.refine", 0.0))
 
 
 #: where the fp64 residual of a ``sweep="device"`` refinement runs, per JAX
@@ -156,7 +165,7 @@ def _residual_dev_fn():
 def refine_solve_device(a, f, b: np.ndarray, *,
                         tol: float = DEFAULT_TOL, max_iter: int = 10,
                         sweep_bs: Optional[int] = None,
-                        rt: Optional[int] = None
+                        rt: Optional[int] = None, ctx=None
                         ) -> tuple[np.ndarray, RefineInfo]:
     """Device-resident refinement for the ``sweep="device"`` solve path.
 
@@ -169,16 +178,18 @@ def refine_solve_device(a, f, b: np.ndarray, *,
     ``jax.enable_x64``, and the only per-iteration host↔device traffic is
     the residual-norm scalar — the ``float()`` that also serves as the sync
     point for the level-bucket dispatches queued by the sweep. Stopping
-    rules (tol / max_iter / stall) are shared with :func:`refine_solve`.
+    rules (tol / max_iter / stall) are shared with :func:`refine_solve`,
+    and so are the spans on ``ctx`` (``solve.sweep``, with the first
+    pass's ``solve.sweep.setup`` inside it, and ``solve.refine``).
     ``b``: ``(n,)`` or ``(n, k)``; returns ``(x fp64 host, RefineInfo)``.
     """
     import jax
     import jax.numpy as jnp
 
+    from repro.core.reqctx import RequestContext, span
     from repro.kernels.ops import rhs_width
     from repro.sparse.multifrontal import _device_sweep_passes
 
-    pc = time.perf_counter
     b = np.asarray(b, dtype=np.float64)
     single = b.ndim == 1
     b2 = b[:, None] if single else b
@@ -186,44 +197,42 @@ def refine_solve_device(a, f, b: np.ndarray, *,
     nb = float(np.linalg.norm(b2))
     if nb == 0.0:
         return np.zeros_like(b), RefineInfo(0, [0.0], True)
+    own = ctx if ctx is not None else RequestContext.mint()
+    spans0 = dict(own.spans)
     residual_step = _residual_dev_fn()
     rows = np.repeat(np.arange(n, dtype=np.int32), np.diff(a.indptr))
 
     def sweep(r32):
         """f32 sweep pass on a device (n, k) block → device (n, k) f32."""
         x = jnp.zeros((n + 1, rhs_width(k)), jnp.float32).at[:n, :k].set(r32)
-        return _device_sweep_passes(f, x, sweep_bs=sweep_bs, rt=rt)[:n, :k]
+        return _device_sweep_passes(f, x, sweep_bs=sweep_bs, rt=rt,
+                                    ctx=own)[:n, :k]
 
     with jax.enable_x64(True):
         data_d = jnp.asarray(a.data, jnp.float64)
         rows_d = jnp.asarray(rows)
         cols_d = jnp.asarray(a.indices, jnp.int32)
         b_d = jnp.asarray(b2, jnp.float64)
-    t0 = pc()
-    dx = sweep(jnp.asarray(b2, jnp.float32))
-    with jax.enable_x64(True):
-        x = dx.astype(jnp.float64)
-    t_sweep = pc() - t0
+    with span(own, "solve.sweep"):
+        dx = sweep(jnp.asarray(b2, jnp.float32))
+        with jax.enable_x64(True):
+            x = dx.astype(jnp.float64)
     residuals: List[float] = []
     iters = 0
-    t_res = 0.0
     while True:
-        t0 = pc()
-        with jax.enable_x64(True):
+        with span(own, "solve.refine"), jax.enable_x64(True):
             r32, nrm = residual_step(data_d, rows_d, cols_d, x, b_d)
             rel = float(nrm) / nb       # the one per-iteration scalar sync
-        t_res += pc() - t0
         residuals.append(rel)
         stop, ok = _should_stop(residuals, tol, iters, max_iter)
         if stop:
             break
-        t0 = pc()
-        dx = sweep(r32)
-        with jax.enable_x64(True):
-            x = x + dx.astype(jnp.float64)
-        t_sweep += pc() - t0
+        with span(own, "solve.sweep"):
+            dx = sweep(r32)
+            with jax.enable_x64(True):
+                x = x + dx.astype(jnp.float64)
         iters += 1
     with jax.enable_x64(True):
         out = np.asarray(x, dtype=np.float64)
     return (out[:, 0] if single else out,
-            RefineInfo(iters, residuals, ok, t_sweep, t_res))
+            _info(iters, residuals, ok, own, spans0))
