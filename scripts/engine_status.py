@@ -228,6 +228,13 @@ def render_server(host: str, port: int, show_all_metrics: bool) -> int:
             # work on every solve
             print(f"  compile_ahead programs lowered {int(programs)} "
                   f"over {int(m.get('solve.requests', 0))} solves")
+        hits = m.get("factor.structure.hits")
+        misses = m.get("factor.structure.misses")
+        if hits is not None or misses is not None:
+            # a miss builds a plan's schedule and routes; a warm engine
+            # should only hit
+            print(f"  factor structure hits {int(hits or 0)} "
+                  f"misses {int(misses or 0)}")
         ov = m.get("solve.overlap_efficiency")
         if ov is not None:
             print(f"  overlap efficiency {ov:.2f} "

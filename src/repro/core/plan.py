@@ -12,8 +12,12 @@ everything that is a pure function of the sparsity structure:
 so a cache hit skips straight to numeric factorization
 (:func:`repro.sparse.multifrontal.multifrontal_cholesky` /
 :func:`repro.sparse.numeric.sparse_cholesky` both accept the precomputed
-``sym``). :class:`PlanBuilder` composes ``ReorderSelector.select_batch``
-(device inference), ``repro.sparse.reorder`` and
+``sym``). The plan also keeps, in the process only, the factorization's
+pattern-only structure per numeric policy
+(:class:`repro.sparse.multifrontal.FactorStructure`), so refactorizations
+with new values skip the schedule, the extend-add routes and the compile.
+:class:`PlanBuilder` composes ``ReorderSelector.select_batch`` (device
+inference), ``repro.sparse.reorder`` and
 ``repro.sparse.symbolic.symbolic_cholesky`` into plans, front-ended by the
 two-tier :class:`repro.core.plan_cache.TwoTierPlanCache`.
 """
@@ -41,6 +45,14 @@ class ExecutionPlan:
 
     Valid for *any* matrix sharing ``fingerprint`` (values don't enter any
     field), which is what makes the plan cacheable and persistable.
+
+    ``structures`` is transient: the factorization's pattern-only
+    structure (:class:`repro.sparse.multifrontal.FactorStructure`: level
+    schedule, extend-add plans, compiled programs), one per
+    :func:`repro.sparse.multifrontal.structure_key` policy, filled by the
+    first :func:`execute_plan` under that policy and read by every later
+    one. It is neither pickled (a plan from the disk tier or the RPC
+    rebuilds it on first use) nor part of equality or repr.
     """
 
     fingerprint: str
@@ -49,6 +61,17 @@ class ExecutionPlan:
     sym: SymbolicFactor         # symbolic analysis of the *permuted* pattern
     predicted_flops: int        # factorization cost model: sym.flops
     meta: Dict[str, float] = dataclasses.field(default_factory=dict)
+    structures: Dict[tuple, object] = dataclasses.field(
+        default_factory=dict, init=False, repr=False, compare=False)
+
+    def __getstate__(self):
+        state = self.__dict__.copy()
+        del state["structures"]
+        return state
+
+    def __setstate__(self, state):
+        self.__dict__.update(state)
+        self.structures = {}
 
     @property
     def n(self) -> int:
@@ -288,7 +311,8 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
     :class:`repro.core.metrics.MetricsRegistry` passed as ``metrics``
     mirrors this call's spans into ``stage.<name>`` histograms and its
     counts into counters (``compile_ahead.programs``: the programs
-    ``compile_ahead`` lowered), and
+    ``compile_ahead`` lowered; ``factor.structure.hits`` / ``.misses``:
+    factorizations that found or built the plan's structure), and
     records the backend's ``solve.overlap_efficiency`` gauge, the sweep
     substrate (``solve.sweep.<mode>`` counters) and the refinement
     behavior (``solve.refine_iterations`` histogram plus per-count
@@ -318,12 +342,18 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
     eff_sweep = sweep
     fstats: dict = {}
     if solver == "multifrontal":
-        from repro.sparse.multifrontal import (multifrontal_cholesky,
-                                               multifrontal_solve)
+        from repro.sparse.multifrontal import (FactorStructure,
+                                               multifrontal_cholesky,
+                                               multifrontal_solve,
+                                               structure_key)
         if (backend in ("pallas", "batched", "pipelined")
                 or sweep == "device") and solve_dtype == "fp64":
             eff_dtype = "fp32_refine"  # f32 factor and/or f32 sweeps
         dtype = np.float64 if eff_dtype == "fp64" else np.float32
+        # the plan's structure under this policy: one entry, also when two
+        # requests race on a cold plan
+        structure = plan.structures.setdefault(
+            structure_key(backend, pad, bs), FactorStructure())
         # ctx rides into the numeric phase: the backends open their spans
         # on it, and the level-scheduled ones re-check the deadline at
         # level boundaries and abandon the factorization mid-flight with
@@ -331,7 +361,8 @@ def execute_plan(a: CSRMatrix, plan: ExecutionPlan,
         with span(own, "factor"):
             f = multifrontal_cholesky(pa, sym=plan.sym, backend=backend,
                                       dtype=dtype, pad=pad, bs=bs,
-                                      ctx=own if ctx is None else ctx)
+                                      ctx=own if ctx is None else ctx,
+                                      structure=structure)
         fstats = f.stats
         with span(own, "solve"):
             if eff_sweep == "auto":

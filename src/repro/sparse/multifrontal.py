@@ -38,6 +38,13 @@ private one): ``factor.schedule``, ``factor.routes``,
 ``factor.compile_ahead``, per level-bucket ``factor.assemble`` and
 ``factor.device`` > ``factor.drain``, and ``solve.sweep.setup``.
 
+The first three depend on the sparsity pattern alone. A
+:class:`FactorStructure` handed to :func:`multifrontal_cholesky` keeps
+what they build — the level schedule, the extend-add plans, the fact that
+the kernel programs are compiled — so every later factorization of the
+same pattern under the same policy only looks them up
+(:class:`repro.core.plan.ExecutionPlan` keeps one per policy).
+
 The triangular solves are level-batched too: :func:`multifrontal_solve`
 stacks each level's factors into (B, P, P)/(B, R, P) tensors once and runs
 batched substitution sweeps per level-bucket. Three sweep modes, all
@@ -65,6 +72,7 @@ analytic cost model agree in ordering.
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import List, Literal, Optional, Tuple
 
@@ -75,13 +83,49 @@ from .csr import CSRMatrix
 from .schedule import FrontPlan, LevelSchedule, build_schedule
 from .symbolic import SymbolicFactor, supernodes, symbolic_cholesky
 
-__all__ = ["MultifrontalFactor", "multifrontal_cholesky", "multifrontal_solve",
+__all__ = ["MultifrontalFactor", "FactorStructure", "structure_key",
+           "multifrontal_cholesky", "multifrontal_solve",
            "factor_and_solve_timed"]
 
 Backend = Literal["numpy", "pallas", "batched", "pipelined"]
 
 #: backends that factor fronts in f32 on device
 DEVICE_BACKENDS = ("pallas", "batched", "pipelined")
+
+#: supernode amalgamation of the numeric phase (``relax`` by default)
+RELAX = 8
+
+
+@dataclasses.dataclass
+class FactorStructure:
+    """The pattern-only part of a factorization under one policy.
+
+    ``schedule`` (supernodes + level schedule) and its ``stats`` for every
+    backend; for ``pipelined`` also the extend-add plans (``ea_plans``, one
+    per destination bucket) and whether the schedule's kernel programs are
+    compiled (``compiled``). The first factorization handed an empty
+    structure fills it; every later one reads it. A concurrent first use
+    waits on ``lock`` for the build instead of repeating it. Holds no
+    coefficient, so it is valid for every matrix of the pattern; it lives
+    in the process only (compiled programs do not travel).
+    """
+
+    schedule: Optional[LevelSchedule] = None
+    stats: Optional[dict] = None
+    ea_plans: Optional[dict] = None
+    compiled: bool = False
+    lock: threading.Lock = dataclasses.field(
+        default_factory=threading.Lock, repr=False, compare=False)
+
+
+def structure_key(backend: str, pad: str, bs: Optional[int],
+                  relax: int = RELAX) -> tuple:
+    """The inputs a :class:`FactorStructure` is built from: ``relax`` and
+    ``pad`` shape the schedule, ``bs`` the compiled programs, and the
+    backend family what is kept (only ``pipelined`` routes on the device;
+    every other backend keeps the schedule alone)."""
+    return (relax, pad, bs,
+            "pipelined" if backend == "pipelined" else "schedule")
 
 
 def _check_deadline(ctx, stage: str) -> None:
@@ -197,12 +241,13 @@ def _partial_factor_pallas(F: np.ndarray, npiv: int):
 def multifrontal_cholesky(
     a: CSRMatrix,
     sym: Optional[SymbolicFactor] = None,
-    relax: int = 8,
+    relax: int = RELAX,
     backend: Backend = "numpy",
     dtype: np.dtype | type = np.float64,
     pad: str = "pow2",
     bs: Optional[int] = None,
     ctx=None,
+    structure: Optional[FactorStructure] = None,
 ) -> MultifrontalFactor:
     """Numeric supernodal factorization of an SPD CSR matrix.
 
@@ -224,6 +269,14 @@ def multifrontal_cholesky(
     :class:`~repro.core.reqctx.DeadlineExceeded` once it has passed —
     serving-path deadline discipline extends into the numeric solve
     instead of stopping at plan build.
+
+    ``structure`` is the :class:`FactorStructure` of ``sym`` under
+    :func:`structure_key` of this call's ``backend``/``pad``/``bs``/
+    ``relax``: an empty one is filled, a filled one is read, and its
+    spans then time the look-ups (``factor.schedule`` carries the stat
+    ``cached``, ``factor.compile_ahead`` ``programs=0``). It adds
+    ``factor.structure.hits`` or ``factor.structure.misses`` to the
+    context's counts. None builds everything for this call alone.
     """
     from repro.core.reqctx import RequestContext, span
 
@@ -232,20 +285,32 @@ def multifrontal_cholesky(
     spans0 = dict(rec.spans)
     if sym is None:
         sym = symbolic_cholesky(a)
-    with span(rec, "factor.schedule"):
-        snode_ptr, snode_of = supernodes(sym, relax=relax)
-        schedule = build_schedule(sym, snode_ptr, snode_of, pad=pad)
+    st = FactorStructure() if structure is None else structure
+    with st.lock:
+        hit = st.schedule is not None
+        if structure is not None:
+            rec.add_count("factor.structure."
+                          + ("hits" if hit else "misses"), 1)
+        with span(rec, "factor.schedule", cached=int(hit)):
+            if not hit:
+                snode_ptr, snode_of = supernodes(sym, relax=relax)
+                schedule = build_schedule(sym, snode_ptr, snode_of, pad=pad)
+                st.stats = schedule.stats()
+                st.schedule = schedule
+        _check_deadline(ctx, "factorization start")
+        if backend == "pipelined":
+            _pipelined_structure(st, rec, bs)
+    schedule = st.schedule
     eff_dtype = np.dtype(np.float32 if backend in DEVICE_BACKENDS else dtype)
 
     timings: dict = {}
     device_stacks = None
-    _check_deadline(ctx, "factorization start")
     if backend in ("batched", "pipelined"):
         if backend == "batched":
             fronts = _factor_batched(a, schedule, rec, bs=bs, ctx=ctx)
         else:
-            fronts, device_stacks = _factor_pipelined(a, schedule, rec,
-                                                      bs=bs, ctx=ctx)
+            fronts, device_stacks = _factor_pipelined(
+                a, schedule, st.ea_plans, rec, bs=bs, ctx=ctx)
         d = rec.spans_since(spans0)
         t_drain = d.get("factor.drain", 0.0)
         timings = _overlap_timings(d.get("factor.assemble", 0.0),
@@ -254,7 +319,7 @@ def multifrontal_cholesky(
     else:
         fronts = _factor_sequential(a, schedule, backend, eff_dtype)
 
-    stats = dict(schedule.stats())  # nsup, nlevels, widths, occupancy, flops
+    stats = dict(st.stats)  # nsup, nlevels, widths, occupancy, flops
     stats.update(n=a.n,
                  peak_front=max((fp.m for fp in schedule.fronts), default=0),
                  nnz_L=sym.nnz_L, fill=sym.fill, sym_flops=sym.flops,
@@ -473,8 +538,34 @@ def _pipelined_calls(ops, schedule: LevelSchedule, ea_plans: dict,
     return calls
 
 
-def _factor_pipelined(a: CSRMatrix, schedule: LevelSchedule, rec,
-                      bs: Optional[int] = None, ctx=None
+def _pipelined_structure(st: FactorStructure, rec,
+                         bs: Optional[int]) -> None:
+    """Fill in the pipelined backend's part of ``st`` where it is missing:
+    the extend-add plans of ``st.schedule`` (span ``factor.routes``) and,
+    compiled concurrently before the first dispatch
+    (:func:`repro.kernels.ops.compile_ahead`), every kernel program it
+    runs (span ``factor.compile_ahead``, the number of programs lowered as
+    its ``programs`` stat and in ``rec.counts["compile_ahead.programs"]``;
+    0 once ``st.compiled``)."""
+    from repro.core.reqctx import span
+    from repro.kernels import ops
+
+    with span(rec, "factor.routes"):
+        if st.ea_plans is None:
+            st.ea_plans = {
+                key: _extend_add_plan(st.schedule, sorted(srcs.items()))
+                for key, srcs in _route_contributions(st.schedule).items()}
+    with span(rec, "factor.compile_ahead") as sp:
+        calls = ([] if st.compiled
+                 else _pipelined_calls(ops, st.schedule, st.ea_plans, bs))
+        ops.compile_ahead(calls)
+        st.compiled = True
+        sp.set_metadata(programs=len(calls))
+    rec.add_count("compile_ahead.programs", len(calls))
+
+
+def _factor_pipelined(a: CSRMatrix, schedule: LevelSchedule, ea_plans: dict,
+                      rec, bs: Optional[int] = None, ctx=None
                       ) -> Tuple[List[_Front], dict]:
     """Pipelined device-resident factorization.
 
@@ -491,12 +582,9 @@ def _factor_pipelined(a: CSRMatrix, schedule: LevelSchedule, rec,
     host-side triangular sweeps. The factored device stacks are *also*
     returned (second element) and retained on the factor: ``sweep="device"``
     slices L11/L21 straight out of them, so device sweeps never re-upload
-    the factors the drain just pulled down. Before the first dispatch,
-    every kernel program of the schedule is compiled concurrently
-    (:func:`repro.kernels.ops.compile_ahead`), under the span
-    ``factor.compile_ahead`` with the number of programs as its
-    ``programs`` stat and in ``rec.counts["compile_ahead.programs"]``.
-    Spans go to ``rec``; ``ctx`` holds the deadline.
+    the factors the drain just pulled down. ``ea_plans`` and the compiled
+    programs come from :func:`_pipelined_structure`. Spans go to ``rec``;
+    ``ctx`` holds the deadline.
     """
     import jax.numpy as jnp
 
@@ -505,14 +593,6 @@ def _factor_pipelined(a: CSRMatrix, schedule: LevelSchedule, rec,
 
     nsup = schedule.nsup
     fronts: List[Optional[_Front]] = [None] * nsup
-    with span(rec, "factor.routes"):
-        ea_plans = {key: _extend_add_plan(schedule, sorted(srcs.items()))
-                    for key, srcs in _route_contributions(schedule).items()}
-    with span(rec, "factor.compile_ahead") as sp:
-        calls = _pipelined_calls(ops, schedule, ea_plans, bs)
-        ops.compile_ahead(calls)
-        sp.set_metadata(programs=len(calls))
-    rec.add_count("compile_ahead.programs", len(calls))
     dev: dict = {}             # (level, bucket) -> factored device stack
     for li in range(schedule.nlevels):
         _check_deadline(ctx, f"pipelined dispatch level "

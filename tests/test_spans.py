@@ -175,7 +175,13 @@ def test_solve_spans_are_trace_events(grid, solved, tmp_path):
         ids = {stats.get("request_id") for _, stats in ev[stage]}
         assert ids == ({ctx.request_id} if parent is None else {None}), \
             stage
+    # the plan's structure is warm: its programs are compiled and its
+    # schedule is looked up; the per-factor sweep stacks still compile
     (_, stats), = ev["factor.compile_ahead"]
+    assert stats["programs"] == 0
+    (_, stats), = ev["factor.schedule"]
+    assert stats["cached"] == 1
+    (_, stats), = ev["solve.sweep.setup"]
     assert stats["programs"] > 0
 
 
