@@ -1,13 +1,17 @@
 """Matrices of a deployment, made from the seed with NumPy and SciPy alone.
 
 A configuration file names a stencil and a grid; the traffic mix draws the
-coefficients. Every matrix is a weighted graph Laplacian of the stencil's
-grid plus a positive diagonal shift: off-diagonal entry ``-w`` per edge,
-diagonal the row's weight sum plus the shift. That is symmetric, positive
-definite and diagonally dominant, so every factorization succeeds.
+coefficients. A stencil is its list of neighbour offsets, one per edge
+direction, each lexicographically positive (its first nonzero entry is +1),
+so the neighbour has the larger row-major index. Every matrix is a weighted
+graph Laplacian of the stencil's grid plus a positive diagonal shift:
+off-diagonal entry ``-w`` per edge, diagonal the row's weight sum plus the
+shift. That is symmetric, positive definite and diagonally dominant, so
+every factorization succeeds.
 """
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass
 
 import numpy as np
@@ -15,8 +19,15 @@ import scipy.sparse as sp
 
 __all__ = ["Pattern", "stencil_edges", "pattern", "values"]
 
-#: stencil name -> grid dimensions it lives on
-STENCILS = {"5-point": 2, "7-point": 3}
+#: stencil name -> its neighbour offsets; a grid has as many dimensions as
+#: an offset has entries
+STENCILS = {
+    "5-point": [(1, 0), (0, 1)],
+    "7-point": [(1, 0, 0), (0, 1, 0), (0, 0, 1)],
+    # HPCG's operator: the 13 positive offsets of the 3 x 3 x 3 box
+    "27-point": [o for o in itertools.product((-1, 0, 1), repeat=3)
+                 if o > (0, 0, 0)],
+}
 
 
 @dataclass
@@ -37,20 +48,22 @@ class Pattern:
 
 
 def stencil_edges(config: dict) -> tuple:
-    """(n, u, v): the grid's nearest-neighbour edges, ``u < v``."""
+    """(n, u, v): one block of edges per stencil offset, in the stencil's
+    order, each edge ``u < v``."""
     grid = tuple(int(g) for g in config["grid"])
-    if STENCILS.get(config["stencil"]) != len(grid):
+    offsets = STENCILS.get(config["stencil"])
+    if offsets is None or len(offsets[0]) != len(grid):
         raise ValueError(f"stencil {config['stencil']!r} does not fit grid "
                          f"{grid}")
     idx = np.arange(int(np.prod(grid)), dtype=np.int64).reshape(grid)
+    # an offset entry -> the slices of the edges' u and v ends on its axis
+    ends = {-1: (slice(1, None), slice(None, -1)), 0: (slice(None),) * 2,
+            1: (slice(None, -1), slice(1, None))}
     us, vs = [], []
-    for ax in range(len(grid)):
-        lo = [slice(None)] * len(grid)
-        hi = [slice(None)] * len(grid)
-        lo[ax] = slice(None, -1)
-        hi[ax] = slice(1, None)
-        us.append(idx[tuple(lo)].ravel())
-        vs.append(idx[tuple(hi)].ravel())
+    for off in offsets:
+        lo, hi = zip(*(ends[d] for d in off))
+        us.append(idx[lo].ravel())
+        vs.append(idx[hi].ravel())
     return idx.size, np.concatenate(us), np.concatenate(vs)
 
 

@@ -308,8 +308,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
                 result["metrics"][m["name"]] = {"value": value,
                                                 "unit": m["unit"]}
     # a request that raised never answered: that is for ``correct`` too
+    t0 = pc()
     checks = [{"name": "unanswered", "value": failed, "limit": 0,
                "ok": failed == 0}] + loop.check(window.records)
+    log(f"checks of the window's answers: {pc() - t0:.3f} s")
     loop.close()
     result["correct"] = all(c["ok"] for c in checks) and len(done) > 0
     result["checks"] = {c["name"]: {"value": c["value"],

@@ -4,6 +4,8 @@ calling ``run_cell`` with the device described), and ``correct`` comes out
 false for the control and for each fault a cell can have, planted in the
 timed path."""
 import copy
+import json
+import shutil
 import sys
 import time
 from pathlib import Path
@@ -216,3 +218,56 @@ def test_unanswered_requests_are_not_correct(solve_cell, engine):
     r = _run(solve_cell, _Raising(engine, None), RefactorLoop, seconds=0.2)
     assert r["correct"] is False and r["failed"] == r["attempted"] > 0
     assert r["checks"]["unanswered"]["value"] == r["failed"]
+
+
+@pytest.fixture(scope="module")
+def hpcg_cell(tmp_path_factory):
+    """A 27-point configuration and its cell, added by files alone: a copy
+    of the benchmark with one more configuration file and one more cell,
+    resolved by ``load_cell`` as a run resolves it."""
+    root = tmp_path_factory.mktemp("bench27")
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    config = json.loads((ROOT / "bench/configs/stencil3d.json").read_text())
+    config.update(name="hpcg27", stencil="27-point", grid=[6, 6, 6],
+                  source="https://github.com/hpcg-benchmark/hpcg")
+    spec["configs"].append({"name": "hpcg27", "source": config["source"],
+                            "file": "bench/configs/hpcg27.json",
+                            "reduced": ["grid"], "why": "27-point 3-D"})
+    spec["workloads"].append({"name": "hpcg27.solve", "config": "hpcg27",
+                              "traffic": "refactor_1rhs", "chips": 1,
+                              "why": "the refactor loop on HPCG's operator"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "stencil3d.solve" in m.get("workloads", []):
+            m["workloads"].append("hpcg27.solve")
+    (root / "bench/configs").mkdir(parents=True)
+    (root / "bench/traffic").mkdir()
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    (root / "bench/configs/hpcg27.json").write_text(json.dumps(config))
+    shutil.copy(ROOT / "bench/traffic/refactor_1rhs.json",
+                root / "bench/traffic")
+    cell = harness.load_cell("hpcg27.solve", root)
+    cell["traffic"]["value_sets"] = 4
+    return cell
+
+
+def test_27_point_cell_from_files_runs_correct(hpcg_cell, engine, capsys):
+    assert {m["name"] for m in hpcg_cell["end_to_end"]} == \
+        {"solve_ms", "setup_s"}
+    assert hpcg_cell["per_layer"]
+    r = _run(hpcg_cell, engine, RefactorLoop)
+    assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0
+    assert r["checks"]["forward_error_max"]["value"] < 1e-12
+    # the checks' cost is on a progress line, not in the result
+    assert "[bench] checks of the window's answers: " in capsys.readouterr().out
+    assert "check_s" not in r
+
+
+@pytest.mark.parametrize("fault", ["stale_factor", "altered_answer",
+                                   "control"])
+def test_27_point_faults_are_not_correct(hpcg_cell, engine, fault):
+    bad = (control.ControlEngine(engine) if fault == "control"
+           else _Faulty(engine, fault))
+    r = _run(hpcg_cell, bad, RefactorLoop)
+    assert r["correct"] is False
+    assert r["checks"]["residual_max"]["value"] > \
+        r["checks"]["residual_max"]["limit"]

@@ -144,3 +144,54 @@ def test_reference_solve_and_residual():
         < 1e-12
     assert reference.is_permutation(np.arange(n)[::-1], n)
     assert not reference.is_permutation(np.zeros(n, int), n)
+
+
+def _stencil_matrix(stencil, grid, seed=4, shift=(0.5, 1.5)):
+    n, u, v = deploy.stencil_edges({"stencil": stencil, "grid": grid})
+    pat = deploy.pattern(n, u, v)
+    rng = np.random.default_rng(seed)
+    return reference.matrix(pat.indptr, pat.indices,
+                            deploy.values(pat, rng, (1, 2), shift)), rng
+
+
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("stencil, grid", [("5-point", [17, 13]),
+                                           ("7-point", [7, 6, 5]),
+                                           ("27-point", [7, 6, 5])])
+def test_reference_solve_is_certified_and_agrees_with_superlu(stencil, grid,
+                                                              k):
+    import scipy.sparse.linalg as spla
+
+    a, rng = _stencil_matrix(stencil, grid)
+    b = rng.standard_normal((a.shape[0], k) if k > 1 else a.shape[0])
+    x = reference.solve(a, b)
+    assert x.shape == b.shape
+    g = reference.gershgorin_min(a)
+    xs = spla.spsolve(a.tocsc(), b).reshape(b.shape)
+    for j in range(k):
+        xj, bj = x.reshape(-1, k)[:, j], b.reshape(-1, k)[:, j]
+        assert np.linalg.norm(bj - a @ xj) / g <= 1e-12 * np.linalg.norm(xj)
+        assert reference.relative_error(xj, xs.reshape(-1, k)[:, j]) <= 1e-12
+
+
+def test_reference_solve_of_a_zero_rhs_is_zero():
+    a, _ = _stencil_matrix("7-point", [4, 4, 4])
+    assert not np.any(reference.solve(a, np.zeros(a.shape[0])))
+
+
+def _no_certificate():
+    """The Laplacian with no diagonal shift: Gershgorin's bound is 0."""
+    return _stencil_matrix("7-point", [4, 4, 4], shift=(0.0, 0.0))[0]
+
+
+def _not_symmetric():
+    a = _stencil_matrix("7-point", [4, 4, 4])[0].tolil()
+    a[0, 1] *= 0.5
+    return a.tocsr()
+
+
+@pytest.mark.parametrize("make", [_no_certificate, _not_symmetric])
+def test_reference_solve_refuses_what_it_cannot_certify(make):
+    a = make()
+    with pytest.raises(ValueError, match="reference solve"):
+        reference.solve(a, np.ones(a.shape[0]))
